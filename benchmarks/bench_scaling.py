@@ -56,6 +56,9 @@ def main():
         env["PYTHONPATH"] = os.path.join(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))), "src")
         env.pop("XLA_FLAGS", None)
+        # compile-only collective counts on virtual host devices: the
+        # child must never reach for the chip a parent process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         res = subprocess.run(
             [sys.executable, "-c", SCRIPT.format(devs=devs, nstat=nstat)],
             env=env, capture_output=True, text=True, timeout=600)

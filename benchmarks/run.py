@@ -17,6 +17,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_batch_updates, bench_general_form,
                    bench_matrix_powers, bench_memory, bench_ols,
                    bench_scaling, bench_sums_powers)

@@ -4,7 +4,8 @@ The evaluator stages a trigger body into a single XLA program: every factor
 block is a chain of (big × skinny) or (skinny × skinny) matmuls, and the
 ``+=`` updates donate the view buffers so the update happens in place.
 
-Backends for the rank-k apply (``M += U Vᵀ``) are pluggable:
+Backends for the rank-k apply (``M += U Vᵀ``) are pluggable
+(:class:`Applier`):
   - "xla": plain jnp (default everywhere),
   - "pallas": the VMEM-tiled TPU kernel from ``repro.kernels.rank_update``
     (interpret-mode on CPU; the kernel is the TPU hot path).
@@ -28,6 +29,17 @@ from .program import Program
 
 Array = jax.Array
 Env = Dict[str, Array]
+
+# Views are float32 and must agree with re-evaluation to f32 accuracy.
+# The TPU's default precision for an f32 dot rounds its operands to
+# bfloat16, so every matmul on the engine's path asks for full f32.
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def matmul(a: Array, b: Array) -> Array:
+    """``a @ b`` in full float32 on every backend."""
+    return jnp.matmul(a, b, precision=PRECISION,
+                      preferred_element_type=jnp.float32)
 
 
 def _dim(d, binding: Dict[str, int]) -> int:
@@ -69,7 +81,7 @@ def _eval_node(x: Expr, env: Env, binding, go) -> Array:
     if isinstance(x, ex.Const):
         return jnp.full((1, 1), x.value, dtype=jnp.float32)
     if isinstance(x, ex.MatMul):
-        return go(x.lhs) @ go(x.rhs)
+        return matmul(go(x.lhs), go(x.rhs))
     if isinstance(x, ex.Add):
         terms = [go(t) for t in x.terms]
         return functools.reduce(jnp.add, terms)
@@ -122,19 +134,33 @@ def build_evaluator(program: Program,
 
 
 def _apply_lowrank_xla(view: Array, u: Array, v: Array) -> Array:
-    return view + u @ v.T
+    return view + matmul(u, v.T)
 
 
-def _get_apply_fn(backend: str):
-    if backend == "xla":
-        return _apply_lowrank_xla
-    if backend == "pallas":
-        # rank_update_batched subsumes the single-update case (a 2-D
-        # (n, k) factor pair is the T=1 stack), so every trigger apply —
-        # per-update or stacked batch — goes through the one-pass kernel.
+class Applier:
+    """The rank-k apply ``view + u vᵀ`` of one staged trigger.
+
+    ``"xla"`` is plain jnp; ``"pallas"`` is the one-pass
+    ``rank_update_batched`` kernel, which subsumes the single-update
+    case (a 2-D ``(n, k)`` pair is the T=1 stack).  A Pallas apply whose
+    shapes no kernel blocking fits takes the XLA reference instead;
+    ``fallbacks`` maps each such view to the reason, filled as the
+    trigger is traced, so the engine can count every firing that ran it.
+    """
+
+    def __init__(self, backend: str):
+        if backend not in ("xla", "pallas"):
+            raise ValueError(f"unknown apply backend {backend!r}")
+        self.pallas = backend == "pallas"
+        self.fallbacks: Dict[str, str] = {}
+
+    def __call__(self, name: str, view: Array, u: Array, v: Array) -> Array:
+        if not self.pallas:
+            return _apply_lowrank_xla(view, u, v)
         from repro.kernels import ops as rk_ops
-        return rk_ops.rank_update_batched
-    raise ValueError(f"unknown apply backend {backend!r}")
+        return rk_ops.rank_update_batched(
+            view, u, v,
+            on_fallback=lambda why: self.fallbacks.__setitem__(name, why))
 
 
 @functools.lru_cache(maxsize=256)
@@ -215,7 +241,7 @@ def build_trigger_fn(trigger: Trigger, program: Program,
     views are never donated (callers may hold references).
     """
     binding = dict(program.dims if binding is None else binding)
-    apply_fn = _get_apply_fn(apply_backend)
+    apply = Applier(apply_backend)
     written, read_only = trigger_touched_views(trigger)
     if donate:
         _warn_donation_ignored()
@@ -231,7 +257,8 @@ def build_trigger_fn(trigger: Trigger, program: Program,
             env[a.name] = evaluate(a.expr, env, binding, cache)
         for up in trigger.updates:
             if up.kind == "lowrank":
-                env[up.view] = apply_fn(env[up.view], env[up.u], env[up.v])
+                env[up.view] = apply(up.view, env[up.view], env[up.u],
+                                     env[up.v])
             else:
                 env[up.view] = env[up.view] + env[up.d]
         return tuple(env[name] for name in written)
@@ -245,6 +272,7 @@ def build_trigger_fn(trigger: Trigger, program: Program,
         views.update(zip(written, new_vals))
         return views
 
+    run.fallbacks = apply.fallbacks
     return run
 
 
@@ -471,17 +499,20 @@ def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
     agreement tolerance.
     """
     binding = dict(program.dims if binding is None else binding)
-    apply_fn = _get_apply_fn(apply_backend)
     written, read_only = trigger_touched_views(trigger)
     if donate:
         _warn_donation_ignored()
     x = program.inputs[trigger.input_name]
     n_in = _dim(x.shape[0], binding)
     k = trigger.rank
-    use_pallas = apply_backend == "pallas"
+    use_pallas = Applier(apply_backend).pallas  # validates the name too
     compact_names = compact_chain_names(trigger)
 
-    def _compact_core():
+    def _no_kernel(apply: Applier, name: str, why: str) -> None:
+        if use_pallas:
+            apply.fallbacks[name] = why
+
+    def _compact_core(apply: Applier):
         # fully row-local trigger: the factor chain runs on the compact
         # (row_bucket, k) block — sentinel-padded rows carry zero block
         # rows through every preserving constructor and their scatter
@@ -497,18 +528,19 @@ def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
                 env[a.name] = evaluate(a.expr, env, binding, cache)
             for up in trigger.updates:
                 L, R = env[up.u], env[up.v]
+                _no_kernel(apply, up.view, "compact row-slab chain: no "
+                           "slab plan for these rows")
                 env[up.view] = env[up.view].at[rows].add(
-                    jnp.dot(L, R.T, preferred_element_type=jnp.float32),
-                    indices_are_sorted=True)
+                    matmul(L, R.T), indices_are_sorted=True)
             return tuple(env[name] for name in written)
 
         if jit:
             return jax.jit(core, donate_argnums=(0,) if donate else ())
         return core
 
-    def _core(slab: Optional[int], num_slabs: int):
+    def _core(slab: Optional[int], num_slabs: int, apply: Applier):
         if slab is None and compact_names is not None:
-            return _compact_core()
+            return _compact_core(apply)
         # one staged body per slab plan shape (None = XLA scatter path)
         def core(written_vals, read_vals, rows, block, v, slab_ids):
             env: Env = dict(zip(written, written_vals))
@@ -526,32 +558,33 @@ def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
                     continue
                 L, R = env[up.u], env[up.v]
                 if trigger.carriers.get(up.view) != "row_local":
-                    env[up.view] = apply_fn(env[up.view], L, R)
+                    env[up.view] = apply(up.view, env[up.view], L, R)
                     continue
                 view = env[up.view]
                 if slab is not None and view.shape[0] % slab == 0:
                     from repro.kernels import ops as rk_ops
-                    bn = rk_ops._pick_block(view.shape[1], 512)
-                    if view.shape[1] % bn == 0:
+                    bn = rk_ops.rank_update_rows_block(slab, view.shape[1],
+                                                       R.shape[1])
+                    if bn is not None:
                         from repro.kernels.rank_update_rows import \
                             rank_update_rows_pallas
                         env[up.view] = rank_update_rows_pallas(
                             view, slab_ids, L, R, slab=slab, bn=bn,
-                            interpret=rk_ops._interpret_default(None))
+                            interpret=rk_ops.interpret_mode())
                         continue
+                _no_kernel(apply, up.view, "rank_update_rows: no slab "
+                           f"plan or blocking for {view.shape}")
                 # gather-GER-scatter: clamped OOB gather rows are
                 # dropped again by the OOB scatter — exact
                 env[up.view] = view.at[rows].add(
-                    jnp.dot(L[rows], R.T,
-                            preferred_element_type=jnp.float32),
-                    indices_are_sorted=True)
+                    matmul(L[rows], R.T), indices_are_sorted=True)
             return tuple(env[name] for name in written)
 
         if jit:
             return jax.jit(core, donate_argnums=(0,) if donate else ())
         return core
 
-    cores: Dict[Tuple[Optional[int], int], Callable] = {}
+    cores: Dict[Tuple[Optional[int], int], Tuple[Callable, Applier]] = {}
 
     def run(views: Env, rows, block, v) -> Env:
         import numpy as np
@@ -564,15 +597,19 @@ def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
             if plan is not None:
                 slab, slab_ids = plan
         key = (slab, int(np.shape(slab_ids)[0]))
-        core = cores.get(key)
-        if core is None:
-            core = cores[key] = _core(*key)
+        hit = cores.get(key)
+        if hit is None:
+            apply = Applier(apply_backend)
+            hit = cores[key] = (_core(*key, apply), apply)
+        core, apply = hit
         new_vals = core(tuple(views[n] for n in written),
                         tuple(views[n] for n in read_only),
                         rows, block, v, slab_ids)
         views.update(zip(written, new_vals))
+        run.fallbacks = apply.fallbacks  # this firing's slab-plan variant
         return views
 
+    run.fallbacks = {}
     return run
 
 
@@ -654,7 +691,7 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
     distributed path (:mod:`repro.dist.ivm_shard`); identity when None.
     """
     binding = dict(program.dims if binding is None else binding)
-    apply_fn = _get_apply_fn(apply_backend)
+    apply = Applier(apply_backend)
     assigns, updates, recompute, skipped = planned_trigger_sets(
         trigger, program, reeval_views, lazy_views)
     written = tuple(dict.fromkeys(
@@ -685,8 +722,8 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
             env[a.name] = evaluate(a.expr, env, binding, cache)
         for up in updates:
             if up.kind == "lowrank":
-                env[up.view] = cst(apply_fn(env[up.view], env[up.u],
-                                            env[up.v]))
+                env[up.view] = cst(apply(up.view, env[up.view], env[up.u],
+                                         env[up.v]))
             else:
                 env[up.view] = cst(env[up.view] + env[up.d])
         # fresh cache: the assign-phase cache holds pre-update values
@@ -706,6 +743,7 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
         views.update(zip(written, new_vals))
         return views
 
+    run.fallbacks = apply.fallbacks
     run.reeval_views = tuple(sorted(reeval_views))
     run.recomputes = tuple(st.target.name for st in recompute)
     run.skipped = skipped

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .codegen import (_get_apply_fn, build_evaluator,
+from .codegen import (Applier, build_evaluator,
                       build_planned_trigger_fn, build_rowlocal_inplace_fn,
                       build_rowlocal_trigger_fn, build_trigger_fn, evaluate,
                       trigger_flops)
@@ -85,6 +85,9 @@ class EngineStats:
     noop_skips: int = 0           # no-op carriers dropped before any firing
     rowlocal_firings: int = 0     # firings that swept only touched row slabs
     widened_carriers: int = 0     # row-local carriers that fell back dense
+    # apply_backend="pallas": view applies that took the XLA path because
+    # no kernel blocking fits their shapes (one per view per firing)
+    pallas_fallbacks: int = 0
 
     def per_update_seconds(self) -> float:
         return self.trigger_seconds / max(self.updates_timed, 1)
@@ -417,9 +420,11 @@ class IncrementalEngine:
             if not pairs:
                 continue
             P, Q = stack_update_arrays(pairs)
-            apply_fn = _get_apply_fn(self._apply_backend)
-            self.views[input_name] = apply_fn(
-                self.views[input_name], jnp.asarray(P), jnp.asarray(Q))
+            apply = Applier(self._apply_backend)
+            self.views[input_name] = apply(
+                input_name, self.views[input_name], jnp.asarray(P),
+                jnp.asarray(Q))
+            self._note_fallbacks(apply)
             stacked[input_name] = (P, Q, len(pairs))
             pairs.clear()
         return stacked
@@ -561,6 +566,7 @@ class IncrementalEngine:
                                           frozenset(), lazy)
             base = dict(self._tier_base[o])
             out = fn(base, np.asarray(Pb), np.asarray(Qb))
+            self._note_fallbacks(fn)
             for name in sweep:
                 self.views[name] = out[name]
             self.stats.fold_sweeps += len(sweep)
@@ -822,6 +828,7 @@ class IncrementalEngine:
         if not reeval and not lazy:
             fn = self._batched_trigger_fn(input_name, bucket)
             self.views = fn(self.views, P, Q)
+            self._note_fallbacks(fn)
             if self.plan is not None:
                 for up in self.compiled.triggers[input_name].updates:
                     self._accum_rank[up.view] = \
@@ -829,6 +836,7 @@ class IncrementalEngine:
             return
         fn = self._planned_trigger_fn(input_name, bucket, reeval, lazy)
         self.views = fn(self.views, P, Q)
+        self._note_fallbacks(fn)
         recomputed = set(fn.recomputes)
         # count only plan-DIRECTED re-evaluations; recomputed also holds
         # lazy views pulled into the recompute closure for exactness
@@ -936,6 +944,7 @@ class IncrementalEngine:
             elif isinstance(u, (list, tuple)) or isinstance(v, (list, tuple)):
                 u, v = np.asarray(u), np.asarray(v)
             self.views = fn(self.views, u, v)
+            self._note_fallbacks(fn)
         else:
             self._fire(input_name, rank, u, v)
         if self._tiers:
@@ -1109,8 +1118,14 @@ class IncrementalEngine:
                 return self.views
         else:
             self.views = fn(self.views, rows, B, V)
+        self._note_fallbacks(fn)
         return self._rowlocal_epilogue(input_name, carrier, rank_bucket, r,
                                        t0, block, t_count)
+
+    def _note_fallbacks(self, fn) -> None:
+        """Count this firing's Pallas applies that ran the XLA reference
+        (``fn.fallbacks``, see :class:`~repro.core.codegen.Applier`)."""
+        self.stats.pallas_fallbacks += len(getattr(fn, "fallbacks", ()))
 
     def _rowlocal_inplace_fn(self, input_name: str) -> Optional[Callable]:
         """The in-place compact applier for ``input_name``'s trigger

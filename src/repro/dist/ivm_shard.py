@@ -31,9 +31,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.codegen import evaluate, trigger_touched_views
+from repro.core.codegen import evaluate, matmul, trigger_touched_views
 from repro.core.compiler import Trigger
 from repro.core.program import Program
+from repro.dist.sharding import auto_axes
 
 Array = jax.Array
 Env = Dict[str, Array]
@@ -49,6 +50,8 @@ def row_spec(mesh: Mesh, axis: str, shape: Tuple[int, ...]) -> P:
 
 
 def _constrainer(mesh: Mesh, axis: str) -> Callable[[Array], Array]:
+    mesh = auto_axes(mesh)
+
     def constrain(x: Array) -> Array:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, row_spec(mesh, axis, x.shape)))
@@ -56,7 +59,8 @@ def _constrainer(mesh: Mesh, axis: str) -> Callable[[Array], Array]:
 
 
 def _replicate(mesh: Mesh, x: Array) -> Array:
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(auto_axes(mesh), P()))
 
 
 def shard_views(views: Env, mesh: Mesh, axis: Optional[str] = None) -> Env:
@@ -66,6 +70,7 @@ def shard_views(views: Env, mesh: Mesh, axis: Optional[str] = None) -> Env:
     firings start from device-resident shards instead of resharding per
     call.
     """
+    mesh = auto_axes(mesh)
     axis = axis or mesh.axis_names[0]
     out = {}
     for name, x in views.items():
@@ -110,7 +115,7 @@ def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
             env[a.name] = evaluate(a.expr, env, binding, cache)
         for up in trigger.updates:
             if up.kind == "lowrank":
-                new = env[up.view] + env[up.u] @ env[up.v].T
+                new = env[up.view] + matmul(env[up.u], env[up.v].T)
             else:
                 new = env[up.view] + env[up.d]
             env[up.view] = constrain(new)
@@ -174,6 +179,6 @@ def distributed_reeval_matmul(mesh: Mesh, *, jit: bool = True,
     constrain = _constrainer(mesh, axis)
 
     def fn(a: Array, b: Array) -> Array:
-        return constrain(constrain(a) @ constrain(b))
+        return constrain(matmul(constrain(a), constrain(b)))
 
     return jax.jit(fn) if jit else fn

@@ -30,7 +30,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AxisName = Union[str, None]
 # one logical name may map to several mesh axes (e.g. batch → (pod, data))
@@ -77,6 +77,20 @@ class ShardingCtx:
         return tuple(a for a in mapped if a in self.mesh.axis_names)
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.
+
+    Placement in this package is declared with ``with_sharding_constraint``
+    and left to GSPMD, which only accepts ``Auto`` axes; ``jax.make_mesh``
+    builds ``Explicit`` ones.  Called where a ``NamedSharding`` is built
+    (``use_sharding`` and :mod:`repro.dist.ivm_shard`), so callers may pass
+    any mesh.  Same devices, same axis names and sizes."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 _CTX: ContextVar[ShardingCtx] = ContextVar(
     "repro_sharding_ctx", default=ShardingCtx(mesh=None, rules=DEFAULT_RULES))
 
@@ -97,7 +111,7 @@ def use_sharding(mesh: Mesh, rules: Optional[Rules] = None):
     merged = dict(DEFAULT_RULES)
     if rules:
         merged.update(rules)
-    ctx = ShardingCtx(mesh=mesh, rules=merged)
+    ctx = ShardingCtx(mesh=auto_axes(mesh), rules=merged)
     token = _CTX.set(ctx)
     try:
         yield ctx

@@ -110,6 +110,10 @@ class FleetScheduler:
         self._pending_total = 0
         self._cap_total = 0
         self.worker_crashes = 0
+        # exceptions a live worker thread survived (anything but a chaos
+        # crash): counted, and the last one kept for fleet_stats()
+        self.worker_errors = 0
+        self.last_worker_error: Optional[str] = None
         self._threads: List[threading.Thread] = []
         self._stop = threading.Event()
 
@@ -531,8 +535,11 @@ class FleetScheduler:
             except WorkerCrashed:
                 incarnation += 1   # the old worker is gone; a new one
                 continue           # (fresh id) picks up the pieces
-            except Exception:
-                incarnation += 1   # never let one tenant kill the pool
+            except Exception as e:  # noqa: BLE001 — one tenant must not
+                # kill the pool, but the failure is never silent
+                self.worker_errors += 1
+                self.last_worker_error = repr(e)
+                incarnation += 1
                 continue
             if res == "idle":
                 self._sleep(self.config.idle_sleep_s)
@@ -551,6 +558,8 @@ class FleetScheduler:
             "leases": self.leases.stats(),
             "trigger_cache": self.registry.trigger_cache.stats(),
             "worker_crashes": self.worker_crashes,
+            "worker_errors": self.worker_errors,
+            "last_worker_error": self.last_worker_error,
             "commits": sum(t.stats.commits for t in tenants),
             "committed_updates": sum(t.stats.committed_updates
                                      for t in tenants),

@@ -327,6 +327,7 @@ class EngineGuard:
             views.update(zip(written, new))
             return views, nbad
 
+        fused.fallbacks = getattr(inner, "fallbacks", {})
         return (fused, written)
 
     def fire(self, engine, input_name: str, bucket: int, P, Q,
@@ -419,6 +420,7 @@ class EngineGuard:
             self.stats.rollbacks += 1
             raise FiringAborted(repr(e), input_name, "execute") from e
         engine.views = out  # safe either way: bad firings self-selected out
+        engine._note_fallbacks(fn)
         self._pending.append((self._nbad, input_name, P, Q))
 
     def sync(self) -> None:

@@ -77,7 +77,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            *, bq: int = 256, bk: int = 256,
                            causal: bool = True,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Single (batch, head) flash attention: q/k/v (S, hd) → (S, hd)."""
     s, hd = q.shape
     bq = min(bq, s)

@@ -68,7 +68,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                         length: jax.Array, *, chunk: int = 512,
-                        interpret: bool = True):
+                        interpret: bool):
     """Returns (acc, m, l); attention output = acc / l.
 
     q: (g, d); k, v: (s, d); length: scalar int32 array.

@@ -1,16 +1,22 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each wrapper:
-  * validates/normalizes shapes (padding ragged edges where needed),
-  * picks block sizes against a VMEM budget,
-  * runs the kernel in interpret mode on CPU (the container target) and
-    compiled mode on TPU (``interpret=None`` → auto by backend).
+  * picks block sizes Mosaic accepts — the last dimension of a block a
+    multiple of 128 or the whole array dimension, the one before it a
+    multiple of 8 or the whole dimension — against a VMEM budget that
+    counts each block padded to the (8, 128) tile and double-buffered;
+  * takes the XLA reference instead when no blocking fits
+    (``rank_update_batched``, the engine's apply, reports that through
+    ``on_fallback`` so the engine can count it);
+  * runs the kernel compiled on a TPU backend and interpreted elsewhere
+    (:func:`interpret_mode`, the one place that choice is made).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,10 +28,15 @@ from .flash_decode import flash_decode_pallas
 from .rank_update import rank_update_batched_pallas, rank_update_pallas
 from .rank_update_rows import rank_update_rows_pallas, rank_update_rows_ref
 
-VMEM_BUDGET = 12 * 1024 * 1024  # bytes we allow a kernel's working set
+# what a kernel's pipelined blocks may take of the 16 MiB of scoped VMEM
+# Mosaic grants a kernel on v5e by default; the rest is left for the
+# kernel body's own temporaries
+VMEM_BUDGET = 12 * 1024 * 1024
+SUBLANE, LANE = 8, 128
 
-
-def _interpret_default(interpret: Optional[bool]) -> bool:
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether Pallas kernels run interpreted: an explicit choice wins,
+    else compiled on a TPU backend and interpreted on any other."""
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
@@ -46,47 +57,85 @@ def _divisors(n: int) -> Tuple[int, ...]:
 
 
 @functools.lru_cache(maxsize=4096)
-def _pick_block(n: int, cap: int, align: int = 8) -> int:
-    """Largest divisor of n that is ≤ cap, preferring multiples of align.
-
-    Runs on every kernel-wrapper call, so it enumerates divisors in O(√n)
-    (not the O(n) scan this replaced) and memoizes: repeated calls with the
-    warm jit cache cost a dict lookup.
-    """
-    best = 1
+def _pick_block(n: int, cap: int, align: int = SUBLANE) -> int:
+    """Largest divisor of ``n`` that is ≤ ``cap`` and a multiple of
+    ``align``; ``n`` itself (the whole dimension, always a legal block)
+    when there is none.  Memoized: it runs on every wrapper call."""
+    best = n
     for b in _divisors(n):
         if b > cap:
             break
-        if b % align == 0 or b == n or b < align:
+        if b % align == 0:
             best = b
     return best
 
 
-def _shrink_block(n: int, b: int) -> int:
-    """Next divisor of n strictly below b (1 if none)."""
-    cands = [d for d in _divisors(n) if d < b]
-    return cands[-1] if cands else 1
+def _shrink_block(n: int, b: int, align: int) -> Optional[int]:
+    """Next divisor of ``n`` below ``b`` that is a multiple of ``align``
+    (``None`` when there is none)."""
+    cands = [d for d in _divisors(n) if d < b and d % align == 0]
+    return cands[-1] if cands else None
+
+
+def tile_bytes(shape: Sequence[int], itemsize: int = 4) -> int:
+    """VMEM bytes of one block: its last two dimensions pad to the
+    (sublane, 128) tile, 8 sublanes of 32-bit words."""
+    *lead, r, c = (1, *shape) if len(shape) == 1 else shape
+    sub = SUBLANE * 4 // itemsize
+    return (math.prod(lead) * (-(-r // sub) * sub)
+            * (-(-c // LANE) * LANE) * itemsize)
+
+
+def vmem_bytes(blocks: Sequence[Sequence[int]],
+               scratch: Sequence[Sequence[int]] = ()) -> int:
+    """Footprint of a pipelined kernel: every block double-buffered,
+    plus single-buffered f32 scratch tiles of the body."""
+    return (2 * sum(tile_bytes(b) for b in blocks)
+            + sum(tile_bytes(b) for b in scratch))
+
+
+def _fit_blocks(n: int, p: int, footprint: Callable[[int, int], int],
+                cap: int = 512) -> Optional[Tuple[int, int]]:
+    """Row block ``bm`` (8-aligned) and lane block ``bn`` (128-aligned)
+    of an ``(n, p)`` array whose ``footprint(bm, bn)`` fits the budget,
+    shrinking the larger one first; ``None`` when nothing fits."""
+    bm = _pick_block(n, cap, SUBLANE)
+    bn = _pick_block(p, cap, LANE)
+    while footprint(bm, bn) > VMEM_BUDGET:
+        sm = _shrink_block(n, bm, SUBLANE)
+        sn = _shrink_block(p, bn, LANE)
+        if sm is not None and (bm >= bn or sn is None):
+            bm = sm
+        elif sn is not None:
+            bn = sn
+        else:
+            return None
+    return bm, bn
+
+
+def rank_update_blocks(n: int, p: int, t: int, k: int
+                       ) -> Optional[Tuple[int, int]]:
+    """``(bm, bn)`` for a ``(n, p)`` view under ``t`` stacked rank-``k``
+    factor pairs, or ``None`` when no blocking fits VMEM."""
+    return _fit_blocks(n, p, lambda bm, bn: vmem_bytes(
+        [(bm, bn), (t, bm, k), (t, bn, k), (bm, bn)], scratch=[(bm, bn)]))
 
 
 def rank_update(m: jax.Array, u: jax.Array, v: jax.Array,
                 interpret: Optional[bool] = None) -> jax.Array:
     """``m + u @ v.T`` — in-place rank-k view update (trigger apply step)."""
     n, p = m.shape
-    k = u.shape[1]
-    # block choice: tile bytes = 4*(bm*bn + k*(bm+bn)) ≤ budget
-    bm = _pick_block(n, 512)
-    bn = _pick_block(p, 512)
-    while 4 * (bm * bn + k * (bm + bn)) > VMEM_BUDGET and (bm > 8 or bn > 8):
-        bm = max(8, bm // 2) if bm >= bn else bm
-        bn = max(8, bn // 2) if bn > bm else bn
-    if n % bm or p % bn:
-        return ref.rank_update(m, u, v)  # ragged fallback
-    return rank_update_pallas(m, u, v, bm=bm, bn=bn,
-                              interpret=_interpret_default(interpret))
+    blocks = rank_update_blocks(n, p, 1, u.shape[1])
+    if blocks is None:
+        return ref.rank_update(m, u, v)
+    return rank_update_pallas(m, u, v, bm=blocks[0], bn=blocks[1],
+                              interpret=interpret_mode(interpret))
 
 
 def rank_update_batched(m: jax.Array, u: jax.Array, v: jax.Array,
-                        interpret: Optional[bool] = None) -> jax.Array:
+                        interpret: Optional[bool] = None,
+                        on_fallback: Optional[Callable[[str], None]] = None
+                        ) -> jax.Array:
     """``m + Σ_t u[t] @ v[t].T`` — T coalesced trigger applies, one pass.
 
     u: (T, n, k), v: (T, p, k).  Accepts 2-D (n, k)/(p, k) factors as the
@@ -98,20 +147,14 @@ def rank_update_batched(m: jax.Array, u: jax.Array, v: jax.Array,
         v = v[None]
     n, p = m.shape
     t, _, k = u.shape
-    bm = _pick_block(n, 512)
-    bn = _pick_block(p, 512)
-    # tile bytes = 4*(bm*bn + T*k*(bm+bn)) ≤ budget; back off along the
-    # divisor lattice (plain halving can step off it and needlessly lose
-    # the kernel to the ragged fallback)
-    while 4 * (bm * bn + t * k * (bm + bn)) > VMEM_BUDGET and (bm > 1 or bn > 1):
-        if bm >= bn:
-            bm = _shrink_block(n, bm)
-        else:
-            bn = _shrink_block(p, bn)
-    if n % bm or p % bn:
-        return ref.rank_update_batched(m, u, v)  # ragged fallback
-    return rank_update_batched_pallas(m, u, v, bm=bm, bn=bn,
-                                      interpret=_interpret_default(interpret))
+    blocks = rank_update_blocks(n, p, t, k)
+    if blocks is None:
+        if on_fallback is not None:
+            on_fallback(f"rank_update_batched: no VMEM-fitting blocks "
+                        f"for {tuple(m.shape)}")
+        return ref.rank_update_batched(m, u, v)
+    return rank_update_batched_pallas(m, u, v, bm=blocks[0], bn=blocks[1],
+                                      interpret=interpret_mode(interpret))
 
 
 def slab_plan(n: int, rows, *, max_fraction: float = 0.25
@@ -149,6 +192,18 @@ def slab_plan(n: int, rows, *, max_fraction: float = 0.25
     return slab, jnp.asarray(ids.astype(np.int32))
 
 
+def rank_update_rows_block(slab: int, p: int, k: int) -> Optional[int]:
+    """Lane block ``bn`` of the touched-slab kernel for ``slab``-row
+    slabs of a ``(·, p)`` view, or ``None`` when no blocking fits."""
+    bn = _pick_block(p, 512, LANE)
+    while vmem_bytes([(slab, bn), (1, slab, k), (bn, k), (slab, bn)],
+                     scratch=[(slab, bn)]) > VMEM_BUDGET:
+        bn = _shrink_block(p, bn, LANE)
+        if bn is None:
+            return None
+    return bn
+
+
 def rank_update_rows(m: jax.Array, rows, block, v: jax.Array,
                      *, max_fraction: float = 0.25,
                      interpret: Optional[bool] = None) -> jax.Array:
@@ -157,9 +212,9 @@ def rank_update_rows(m: jax.Array, rows, block, v: jax.Array,
     ``rows`` (r,) are the affected row indices (host-concrete), ``block``
     (r, k) the compact left factor, ``v`` (p, k).  Sweeps only the
     touched row slabs through the Pallas kernel — HBM traffic scales
-    with r, not n — and falls back to the dense batched kernel when the
+    with r, not n — and takes the dense batched kernel when the
     affected fraction exceeds ``max_fraction`` (past the crossover the
-    slab gather costs more than it saves) or the shapes don't tile.
+    slab gather costs more than it saves).
     """
     import numpy as np
     n, p = m.shape
@@ -167,20 +222,26 @@ def rank_update_rows(m: jax.Array, rows, block, v: jax.Array,
     block = jnp.asarray(block)
     k = v.shape[1]
     plan = slab_plan(n, rows, max_fraction=max_fraction)
-    dense_u = None
     if plan is None:
         dense_u = jnp.zeros((n, k), v.dtype).at[jnp.asarray(rows)].set(block)
         return rank_update(m, dense_u, v, interpret=interpret)
     slab, slab_ids = plan
-    bn = _pick_block(p, 512)
-    while 4 * (slab * bn + k * (slab + bn)) > VMEM_BUDGET and bn > 8:
-        bn = max(8, bn // 2)
-    if p % bn:
+    bn = rank_update_rows_block(slab, p, k)
+    if bn is None:
         return rank_update_rows_ref(m, jnp.asarray(rows.astype(np.int32)),
                                     block, v)
     u = jnp.zeros((n, k), v.dtype).at[jnp.asarray(rows)].set(block)
     return rank_update_rows_pallas(m, slab_ids, u, v, slab=slab, bn=bn,
-                                   interpret=_interpret_default(interpret))
+                                   interpret=interpret_mode(interpret))
+
+
+def dual_matmul_blocks(n: int, m: int, k: int) -> Optional[Tuple[int, int]]:
+    """``(bm, bn)`` tile of ``a`` (n, m) for :func:`dual_matmul`: the
+    tile and the ``k``-row factor panels, plus ``Vᵀ`` and the ``Pᵀ``
+    accumulator held whole in VMEM; ``None`` when nothing fits."""
+    return _fit_blocks(n, m, lambda bm, bn: vmem_bytes(
+        [(bm, bn), (k, bn), (k, bn), (n // bm, k, bm), (n // bm, k, bm)],
+        scratch=[(k, bm), (k, bn)]))
 
 
 def dual_matmul(a: jax.Array, u: jax.Array, v: jax.Array,
@@ -188,15 +249,11 @@ def dual_matmul(a: jax.Array, u: jax.Array, v: jax.Array,
                 ) -> Tuple[jax.Array, jax.Array]:
     """Fused ``(a @ u, a.T @ v)`` — one HBM pass over ``a``."""
     n, m = a.shape
-    k = u.shape[1]
-    bn = _pick_block(m, 512)
-    # panel bytes = 4*(n*bn + n*k + bn*k + n*k)
-    while 4 * n * (bn + 2 * k) > VMEM_BUDGET and bn > 8:
-        bn = max(8, bn // 2)
-    if m % bn:
+    blocks = dual_matmul_blocks(n, m, u.shape[1])
+    if blocks is None:
         return ref.dual_matmul(a, u, v)
-    return dual_matmul_pallas(a, u, v, bn=bn,
-                              interpret=_interpret_default(interpret))
+    return dual_matmul_pallas(a, u, v, bm=blocks[0], bn=blocks[1],
+                              interpret=interpret_mode(interpret))
 
 
 def sherman_morrison_delta(w: jax.Array, u: jax.Array, v: jax.Array,
@@ -227,7 +284,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     qg = q.reshape(h_kv, group, d)
     kt = k.transpose(1, 0, 2)  # (h_kv, s, d)
     vt = v.transpose(1, 0, 2)
-    interp = _interpret_default(interpret)
+    interp = interpret_mode(interpret)
     chunk = min(chunk, s)
     while s % chunk:
         chunk -= 1
@@ -249,7 +306,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q: (b, s, h, hd); k/v: (b, s, h, hd) — expand GQA before calling.
     vmaps the per-(batch, head) kernel.
     """
-    interp = _interpret_default(interpret)
+    interp = interpret_mode(interpret)
 
     def per_bh(qh, kh, vh):
         return flash_attention_pallas(qh, kh, vh, bq=bq, bk=bk,

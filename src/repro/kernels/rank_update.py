@@ -7,9 +7,10 @@ stream M through VMEM exactly once at full HBM bandwidth while the MXU
 computes the (bm × k) @ (k × bn) tile products.
 
 TPU adaptation (vs the paper's BLAS GER):
-  * M is tiled (bm × bn), both multiples of the (8, 128) f32 VREG tile and
-    128-aligned for the MXU; U/V tiles live in VMEM across a whole row /
-    column of the grid (they are k-skinny, so their footprint is tiny).
+  * M is tiled (bm × bn): bm a multiple of 8 and bn of 128 (or the whole
+    dimension), the (8, 128) f32 VREG tile; U/V tiles live in VMEM across
+    a whole row / column of the grid.  A k-skinny tile still pads its k
+    lanes to 128, which ``ops.rank_update_blocks`` budgets for.
   * the update is done in place via input/output aliasing — M is read and
     written once, the roofline optimum for this op.
   * rank k is padded to the lane width (128) by ``ops.rank_update`` when
@@ -26,12 +27,14 @@ from jax.experimental import pallas as pl
 
 
 DEFAULT_BLOCK = (256, 256)
+# full f32 products: the default TPU precision rounds f32 operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _rank_update_kernel(m_ref, u_ref, v_ref, o_ref):
     # one (bm, bn) tile of M; U tile (bm, k); V tile (bn, k).
     # accumulate in f32 on the MXU, store back in the view dtype.
-    upd = jnp.dot(u_ref[...], v_ref[...].T,
+    upd = jnp.dot(u_ref[...], v_ref[...].T, precision=HIGHEST,
                   preferred_element_type=jnp.float32)
     o_ref[...] = (m_ref[...].astype(jnp.float32) + upd).astype(o_ref.dtype)
 
@@ -39,7 +42,7 @@ def _rank_update_kernel(m_ref, u_ref, v_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def rank_update_pallas(m: jax.Array, u: jax.Array, v: jax.Array,
                        *, bm: int = DEFAULT_BLOCK[0], bn: int = DEFAULT_BLOCK[1],
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """``m + u @ v.T`` with m: (n, p), u: (n, k), v: (p, k)."""
     n, p = m.shape
     k = u.shape[1]
@@ -73,7 +76,7 @@ def _rank_update_batched_kernel(m_ref, u_ref, v_ref, o_ref):
     acc = m_ref[...].astype(jnp.float32)
 
     def body(i, acc):
-        return acc + jnp.dot(u_ref[i], v_ref[i].T,
+        return acc + jnp.dot(u_ref[i], v_ref[i].T, precision=HIGHEST,
                              preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(0, t, body, acc)
@@ -84,7 +87,7 @@ def _rank_update_batched_kernel(m_ref, u_ref, v_ref, o_ref):
 def rank_update_batched_pallas(m: jax.Array, u: jax.Array, v: jax.Array,
                                *, bm: int = DEFAULT_BLOCK[0],
                                bn: int = DEFAULT_BLOCK[1],
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """``m + Σ_t u[t] @ v[t].T`` — the batched trigger hot loop.
 
     m: (n, p); u: (T, n, k); v: (T, p, k) — a stream of T rank-k updates

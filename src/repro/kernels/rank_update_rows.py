@@ -22,8 +22,8 @@ op that was memory-bound to begin with.  This kernel sweeps only the
 Exactness contract: padding slab ids must reference **distinct untouched
 slabs** (each grid row writes its slab once — a repeated id would make
 the aliased read-modify-write order-dependent).  ``ops.rank_update_rows``
-enforces this and falls back to the dense kernel when the affected
-fraction makes slab sweeping pointless.
+enforces this and takes the dense kernel when the affected fraction
+makes slab sweeping pointless.
 """
 
 from __future__ import annotations
@@ -40,7 +40,7 @@ def _rows_kernel(ids_ref, m_ref, u_ref, v_ref, o_ref):
     # one (slab, bn) tile of a touched M slab; U slab (1, slab, k);
     # V tile (bn, k).  ids_ref is consumed by the index maps only.
     del ids_ref
-    upd = jnp.dot(u_ref[0], v_ref[...].T,
+    upd = jnp.dot(u_ref[0], v_ref[...].T, precision=jax.lax.Precision.HIGHEST,
                   preferred_element_type=jnp.float32)
     o_ref[...] = (m_ref[...].astype(jnp.float32) + upd).astype(o_ref.dtype)
 
@@ -50,7 +50,7 @@ def _rows_kernel(ids_ref, m_ref, u_ref, v_ref, o_ref):
 def rank_update_rows_pallas(m: jax.Array, slab_ids: jax.Array,
                             u: jax.Array, v: jax.Array, *,
                             slab: int, bn: int,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool) -> jax.Array:
     """``m + u @ v.T`` sweeping only the row slabs named by ``slab_ids``.
 
     m: (n, p); u: (n, k) with row support contained in the listed slabs;
@@ -96,5 +96,6 @@ def rank_update_rows_ref(m: jax.Array, rows: jax.Array, block: jax.Array,
     """
     # no unique_indices promise: sentinel padding repeats the value n
     return m.at[rows].add(jnp.dot(block, v.T,
+                                  precision=jax.lax.Precision.HIGHEST,
                                   preferred_element_type=jnp.float32),
                           indices_are_sorted=True)

@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import ServeEngine
+from .compile_cache import enable_compile_cache
 from .train import custom_10m, custom_100m
 
 
@@ -101,6 +102,7 @@ def main():
     ap.add_argument("--fivm-bursts", type=int, default=8)
     ap.add_argument("--fivm-burst-size", type=int, default=48)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.fivm:
         serve_fivm(args)

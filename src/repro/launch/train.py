@@ -30,6 +30,7 @@ from repro.dist.fault_tolerance import (FaultToleranceConfig,
                                         FaultTolerantController, RunPhase,
                                         TrainingSupervisor)
 from repro.dist.sharding import use_sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.train import grad_compression as gc
@@ -214,6 +215,7 @@ def main():
                     help="per-heartbeat probability of killing a host")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = resolve_config(args)
     mesh = (make_local_mesh(args.model_parallel)

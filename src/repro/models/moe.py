@@ -29,7 +29,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import current_ctx, shard
@@ -213,12 +212,12 @@ def moe_block(params: Dict, cfg, x: jax.Array, return_aux: bool = False):
     body = functools.partial(_moe_body, cfg=cfg, model_axis="model",
                              ep=ep, return_aux=return_aux,
                              batch_axes=batch_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None), P(), w_spec, w_spec, w_out_spec)
         + shared_in,
         out_specs=(P(batch_axes, None), P()),
-        check_rep=False)
+        check_vma=False)
     xt = x.reshape(b * s, d)
     out, aux = fn(xt, params["router"], params["w_in"], params["w_gate"],
                   params["w_out"], *shared_args)

@@ -12,10 +12,10 @@ same mesh, gets the *same* function object back, and jax's jit cache
 re-trace and no re-compile.
 
 Process-level by design: XLA executables are not picklable, so true
-on-disk persistence is delegated to jax's own compilation cache
-(``jax.config.update("jax_compilation_cache_dir", …)``), which composes
-with this cache — the key here removes the *re-trace*, the jax cache
-removes the *re-compile* across processes.
+on-disk persistence is delegated to jax's own compilation cache (which
+the entry points turn on, :mod:`repro.launch.compile_cache`), and which
+composes with this cache — the key here removes the *re-trace*, the jax
+cache removes the *re-compile* across processes.
 
 Engines use the process-global instance whenever they execute a plan;
 pass ``trigger_cache=TriggerCache()`` for an isolated one (tests).

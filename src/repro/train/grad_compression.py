@@ -56,7 +56,6 @@ def init_compression(params, rank: int = 4, min_dim: int = 128, seed: int = 0
         return (jnp.zeros(_matrix_shape(p), jnp.float32)
                 if _is_compressible(p, min_dim) else None)
 
-    # jax.tree.map_with_path only exists on newer jax; use the stable alias
     q = jax.tree_util.tree_map_with_path(lambda kp, p: q_init(str(kp), p),
                                          params)
     err = jax.tree.map(e_init, params)
@@ -148,8 +147,6 @@ def compressed_psum(mesh, axis: str, grads, state: CompressionState,
     Bytes on the wire per matrix: 2·n·k instead of n·m.  Matrix leaves
     only; the rest get a plain psum.
     """
-    from jax.experimental.shard_map import shard_map
-
     flat_g, tdef = jax.tree.flatten(grads)
     flat_q = tdef.flatten_up_to(state.q)
 
@@ -171,8 +168,8 @@ def compressed_psum(mesh, axis: str, grads, state: CompressionState,
         return tuple(outs)
 
     spec = P(axis)  # grads arrive batch-sharded over the DP axis
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=tuple(P() for _ in flat_g),
-                   out_specs=tuple(P() for _ in flat_g),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=tuple(P() for _ in flat_g),
+                       out_specs=tuple(P() for _ in flat_g),
+                       check_vma=False)
     return jax.tree.unflatten(tdef, list(fn(*flat_g)))

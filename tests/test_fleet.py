@@ -547,6 +547,33 @@ def test_thread_mode_smoke():
     assert max_abs_diff(tenant.committed_views, ref.views) == 0.0
 
 
+def test_thread_worker_error_is_counted():
+    """A tenant whose firing raises does not kill the worker pool, and
+    the error is counted and shown in fleet_stats()."""
+    import time
+    fleet = FleetScheduler(FleetConfig(lease_ttl=0.05, workers=1))
+    prog, inputs = _logit_tenant()
+    tenant = fleet.add_tenant(TenantSpec("t1", prog, {"W": 1}), inputs)
+
+    def explode(*a, **k):
+        raise RuntimeError("firing exploded")
+
+    tenant.engine.apply_updates = explode
+    rng = np.random.default_rng(3)
+    fleet.start()
+    try:
+        assert fleet.submit("t1", "W", *_rank1(rng, 5, 4)) == ADMITTED
+        deadline = time.monotonic() + 30.0
+        while fleet.worker_errors == 0 and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        fleet.stop()
+    stats = fleet.fleet_stats()
+    assert stats["worker_errors"] >= 1
+    assert "firing exploded" in stats["last_worker_error"]
+    assert tenant.dirty() and tenant.stats.commits == 0
+
+
 def test_serve_engine_attach_fleet():
     """ServeEngine routes hot-swap deltas / reads / health through a
     fleet-backed logit view."""

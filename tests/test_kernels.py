@@ -68,8 +68,8 @@ def test_dual_matmul_explicit_blocks(rng):
     a = jnp.asarray(rng.normal(size=(128, 128)), jnp.float32)
     u = jnp.asarray(rng.normal(size=(128, 2)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(128, 2)), jnp.float32)
-    for bn in (32, 64, 128):
-        p1, q1 = dual_matmul_pallas(a, u, v, bn=bn, interpret=True)
+    for bm, bn in ((32, 128), (64, 64), (128, 32)):
+        p1, q1 = dual_matmul_pallas(a, u, v, bm=bm, bn=bn, interpret=True)
         p2, q2 = ref.dual_matmul(a, u, v)
         assert_close(p1, p2, rtol=1e-3)
         assert_close(q1, q2, rtol=1e-3)

@@ -25,8 +25,6 @@ def test_walker_counts_scan_trips():
     assert abs(w.flops - expect) / expect < 0.01
     # XLA's own analysis misses the trip count — that's why the walker exists
     ca = c.cost_analysis()
-    if isinstance(ca, list):  # older jax returns one dict per device
-        ca = ca[0]
     assert ca["flops"] < w.flops / 5
 
 

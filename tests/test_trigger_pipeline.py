@@ -213,8 +213,13 @@ def test_pick_block_properties():
     from repro.kernels.ops import _pick_block
     for n in (1, 8, 63, 64, 96, 100, 160, 256, 512, 777, 1000, 1024):
         for cap in (8, 100, 512):
-            b = _pick_block(n, cap)
-            assert n % b == 0 and 1 <= b <= max(cap, 1)
+            for align in (8, 128):
+                b = _pick_block(n, cap, align)
+                # an aligned divisor within the cap, else the whole dim
+                assert n % b == 0
+                assert b == n or (b % align == 0 and b <= cap)
+    assert _pick_block(1000, 512, 128) == 1000   # no 128-multiple divides
+    assert _pick_block(1000, 512, 8) == 200
 
 
 # -- update queue -------------------------------------------------------------
@@ -330,3 +335,62 @@ def test_batched_cost_model():
     # QR/SVD is view-size independent while the rank-K sweep is not
     big = (4096, 4096)
     assert batched_strategy(big, 512, 2, 2.0 * 4096 ** 3) == "recompress"
+
+
+def _prime_chain(n: int):
+    """``Y1 = X·W1; Y2 = Y1·W2`` with every view ``n × n``."""
+    from repro.core import Program, dim, matmul
+    p = Program(name="prime_chain")
+    X = p.input("X", (dim("N"), dim("N")))
+    W1 = p.input("W1", (dim("N"), dim("N")))
+    W2 = p.input("W2", (dim("N"), dim("N")))
+    Y1 = p.let("Y1", matmul(X, W1))
+    p.let("Y2", matmul(Y1, W2))
+    p.outputs = ["Y1", "Y2"]
+    return p.bind_dims(N=n)
+
+
+@pytest.mark.parametrize("path", ["plain", "guarded", "rowlocal",
+                                  "guarded_rowlocal"])
+def test_pallas_fallbacks_counted(path):
+    """A prime width has no 8- or 128-aligned divisor and its whole
+    block overflows VMEM, so every Pallas apply takes the XLA reference:
+    each firing counts one fallback per view it writes, on every firing
+    path, and the views still match the XLA engine."""
+    from repro.core.factored import RowLocalCarrier
+    n = 1021
+    assert ops.rank_update_blocks(n, n, 1, 1) is None
+    assert ops.slab_plan(n, np.array([3])) is None
+    rng = np.random.default_rng(0)
+    inputs = {name: (rng.standard_normal((n, n)) / n ** 0.5
+                     ).astype(np.float32) for name in ("X", "W1", "W2")}
+    guarded = path.startswith("guarded")
+    engines = [IncrementalEngine(_prime_chain(n), {"X": 1},
+                                 apply_backend=backend, rowlocal_apply="jit",
+                                 guard=True if guarded else None)
+               for backend in ("pallas", "xla")]
+    for eng in engines:
+        eng.initialize(inputs)
+    eng, ref_eng = engines
+    assert eng._guard_fast_path == guarded
+    per_firing = sum(up.kind == "lowrank"
+                     for up in eng.compiled.triggers["X"].updates)
+    assert per_firing == 3
+    firings = 3
+    for i in range(firings):
+        rows = np.array([7 * i, 7 * i + 500], np.int32)
+        block = rng.standard_normal((2, 1)).astype(np.float32)
+        V = (rng.standard_normal((n, 1)) / n).astype(np.float32)
+        carrier = RowLocalCarrier(rows, block, V, n)
+        for e in engines:
+            if path.endswith("rowlocal"):
+                e.apply_update("X", carrier, block=True)
+            else:
+                e.apply_update("X", *carrier.factors(), block=True)
+        assert eng.stats.pallas_fallbacks == per_firing * (i + 1)
+    if path.endswith("rowlocal"):
+        assert eng.stats.rowlocal_firings == firings
+        assert eng.stats.widened_carriers == 0
+    assert ref_eng.stats.pallas_fallbacks == 0
+    for name in ("X", "Y1", "Y2"):
+        assert_close(eng.views[name], ref_eng.views[name])
