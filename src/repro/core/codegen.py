@@ -14,6 +14,7 @@ Backends for the rank-k apply (``M += U Vᵀ``) are pluggable
 from __future__ import annotations
 
 import functools
+import re
 import warnings
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -34,6 +35,22 @@ Env = Dict[str, Array]
 # The TPU's default precision for an f32 dot rounds its operands to
 # bfloat16, so every matmul on the engine's path asks for full f32.
 PRECISION = jax.lax.Precision.HIGHEST
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """Give ``fn`` a stable name before ``jax.jit``: its HLO module is
+    then ``jit_<name>``, which is what a profiler trace's ``XLA Modules``
+    line shows for each execution."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def firing_name(trigger: Trigger, path: str = "") -> str:
+    """``fire_[<path>_]<input>_r<rank>``: the jit name of one firing
+    program (``path`` names the variant: planned, rowlocal, guarded,
+    sharded; the dense firing has none)."""
+    head = f"fire_{path}_" if path else "fire_"
+    return head + re.sub(r"\W", "_", trigger.input_name) + f"_r{trigger.rank}"
 
 
 def matmul(a: Array, b: Array) -> Array:
@@ -125,7 +142,7 @@ def build_evaluator(program: Program,
             out[st.target.name] = val
         return out
 
-    return jax.jit(run) if jit else run
+    return jax.jit(named(run, "evaluate")) if jit else run
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +281,8 @@ def build_trigger_fn(trigger: Trigger, program: Program,
         return tuple(env[name] for name in written)
 
     if jit:
-        core = jax.jit(core, donate_argnums=(0,) if donate else ())
+        core = jax.jit(named(core, firing_name(trigger)),
+                       donate_argnums=(0,) if donate else ())
 
     def run(views: Env, u: Array, v: Array) -> Env:
         new_vals = core(tuple(views[n] for n in written),
@@ -535,7 +553,8 @@ def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
             return tuple(env[name] for name in written)
 
         if jit:
-            return jax.jit(core, donate_argnums=(0,) if donate else ())
+            return jax.jit(named(core, firing_name(trigger, "rowlocal")),
+                           donate_argnums=(0,) if donate else ())
         return core
 
     def _core(slab: Optional[int], num_slabs: int, apply: Applier):
@@ -581,7 +600,8 @@ def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
             return tuple(env[name] for name in written)
 
         if jit:
-            return jax.jit(core, donate_argnums=(0,) if donate else ())
+            return jax.jit(named(core, firing_name(trigger, "rowlocal")),
+                           donate_argnums=(0,) if donate else ())
         return core
 
     cores: Dict[Tuple[Optional[int], int], Tuple[Callable, Applier]] = {}
@@ -733,7 +753,8 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
         return tuple(env[name] for name in written)
 
     if jit:
-        core = jax.jit(core, donate_argnums=(0,) if donate else ())
+        core = jax.jit(named(core, firing_name(trigger, "planned")),
+                       donate_argnums=(0,) if donate else ())
 
     def run(views: Env, u: Array, v: Array) -> Env:
         if not jit:  # jitted cores convert np factors on the C++ arg path

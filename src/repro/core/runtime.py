@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .codegen import (Applier, build_evaluator,
                       build_planned_trigger_fn, build_rowlocal_inplace_fn,
                       build_rowlocal_trigger_fn, build_trigger_fn, evaluate,
@@ -52,14 +53,11 @@ class EngineStats:
     """Engine counters.
 
     ``trigger_seconds`` only accumulates for *blocked* firings (an async
-    dispatch has no meaningful wall time), so per-update timings divide by
-    ``updates_timed`` — counting them against ``updates_applied`` silently
-    under-reports whenever any caller passes ``block=False``.
+    dispatch has no meaningful wall time).
     """
 
     updates_applied: int = 0      # logical updates (a T-batch counts T)
     triggers_fired: int = 0       # trigger firings (a T-batch counts 1)
-    updates_timed: int = 0        # logical updates included in trigger_seconds
     trigger_seconds: float = 0.0
     batches_applied: int = 0
     recompressions: int = 0
@@ -88,9 +86,14 @@ class EngineStats:
     # apply_backend="pallas": view applies that took the XLA path because
     # no kernel blocking fits their shapes (one per view per firing)
     pallas_fallbacks: int = 0
-
-    def per_update_seconds(self) -> float:
-        return self.trigger_seconds / max(self.updates_timed, 1)
+    # firing ranks, summed over committed firings: as stacked, as fired
+    # after re-compression, and as padded to the bucket of the trigger
+    stacked_rank: int = 0
+    fired_rank: int = 0
+    padded_rank: int = 0
+    # trigger fns built (trigger-cache misses).  A rollback keeps it, as
+    # it keeps the cache (guard.txn.restore_snapshot)
+    trigger_builds: int = 0
 
 
 class IncrementalEngine:
@@ -231,6 +234,7 @@ class IncrementalEngine:
         self._donate = donate
         self.mesh = mesh
         self.mesh_axis = mesh_axis
+        self.stats = EngineStats()
         self._evaluator = build_evaluator(self.program, self.binding, jit=jit)
         # planned execution state (repro.plan)
         self.plan = None
@@ -274,7 +278,6 @@ class IncrementalEngine:
         self._pending: Dict[str, List[Tuple[np.ndarray, np.ndarray]]] = {}
         self._pending_since: Dict[str, float] = {}
         self.views: Dict[str, Array] = {}
-        self.stats = EngineStats()
         # failure containment (repro.guard): imported lazily so unguarded
         # engines never pay the import and the core↔guard layering stays
         # one-directional at module load.
@@ -694,10 +697,14 @@ class IncrementalEngine:
         """Build a trigger fn through the shared cache (identical plan
         keys across engine instances reuse the jitted callable — no
         re-trace, no re-compile)."""
+        def build() -> Callable:
+            self.stats.trigger_builds += 1
+            with obs.span("engine.build"):
+                return builder()
+
         if self._trigger_cache is None:
-            return builder()
-        return self._trigger_cache.get_or_build(self._cache_key(tail),
-                                                builder)
+            return build()
+        return self._trigger_cache.get_or_build(self._cache_key(tail), build)
 
     def _bucket_trigger(self, input_name: str, bucket: int) -> Trigger:
         """The trigger IR for (input, stacked-rank bucket)."""
@@ -827,7 +834,8 @@ class IncrementalEngine:
             P, Q = np.asarray(P), np.asarray(Q)  # jit rejects raw lists
         if not reeval and not lazy:
             fn = self._batched_trigger_fn(input_name, bucket)
-            self.views = fn(self.views, P, Q)
+            with obs.span("engine.dispatch"):
+                self.views = fn(self.views, P, Q)
             self._note_fallbacks(fn)
             if self.plan is not None:
                 for up in self.compiled.triggers[input_name].updates:
@@ -835,7 +843,8 @@ class IncrementalEngine:
                         self._accum_rank.get(up.view, 0) + bucket
             return
         fn = self._planned_trigger_fn(input_name, bucket, reeval, lazy)
-        self.views = fn(self.views, P, Q)
+        with obs.span("engine.dispatch"):
+            self.views = fn(self.views, P, Q)
         self._note_fallbacks(fn)
         recomputed = set(fn.recomputes)
         # count only plan-DIRECTED re-evaluations; recomputed also holds
@@ -905,9 +914,15 @@ class IncrementalEngine:
         to quarantine, views untouched) and the firing is transactional
         (a chaos fault or non-finite output rolls back and returns the
         pre-firing views)."""
-        if isinstance(u, DeltaCarrier) or v is None:
-            return self._apply_carrier(input_name, as_carrier(u, v),
-                                       block=block)
+        with obs.span("engine.apply_update", request=True):
+            if isinstance(u, DeltaCarrier) or v is None:
+                return self._apply_carrier(input_name, as_carrier(u, v),
+                                           block=block)
+            return self._apply_update(input_name, u, v, block)
+
+    def _apply_update(self, input_name: str, u: Array, v: Array,
+                      block: bool) -> Dict[str, Array]:
+        """The dense firing of :meth:`apply_update`."""
         rank = self.compiled.triggers[input_name].rank
         if self._tiers and self._inputs_deferrable(input_name):
             # deferred-input fast path: bank the factors and return —
@@ -943,19 +958,23 @@ class IncrementalEngine:
                 u, v = jnp.asarray(u), jnp.asarray(v)
             elif isinstance(u, (list, tuple)) or isinstance(v, (list, tuple)):
                 u, v = np.asarray(u), np.asarray(v)
-            self.views = fn(self.views, u, v)
+            with obs.span("engine.dispatch"):
+                self.views = fn(self.views, u, v)
             self._note_fallbacks(fn)
         else:
             self._fire(input_name, rank, u, v)
         if self._tiers:
             self._cascade_accumulate(input_name, [(u, v)])
         if block:
-            jax.block_until_ready(self.views)
+            with obs.span("engine.block"):
+                jax.block_until_ready(self.views)
             self.stats.trigger_seconds += time.perf_counter() - t0
-            self.stats.updates_timed += 1
             self.stats.sweep_flops_timed += self._sweep_flops(input_name, rank)
         self.stats.updates_applied += 1
         self.stats.triggers_fired += 1
+        self.stats.stacked_rank += rank
+        self.stats.fired_rank += rank
+        self.stats.padded_rank += rank
         self._observe_firing(input_name, rank, 1)
         if self.guard is not None:
             self.guard.after_firing(self)
@@ -1041,11 +1060,13 @@ class IncrementalEngine:
                                             block=block)
             self.stats.widened_carriers += 1
         P, Q = carrier.factors()
-        return self.apply_update(input_name, P, Q, block=block)
+        return self._apply_update(input_name, P, Q, block)
 
     def _apply_rowlocal(self, input_name: str, carrier: RowLocalCarrier,
                         block: bool = False, t_count: int = 1,
-                        poisoned: bool = False) -> Dict[str, Array]:
+                        poisoned: bool = False,
+                        stacked_rank: Optional[int] = None
+                        ) -> Dict[str, Array]:
         """Fire the row-slab trigger for one (possibly stacked)
         row-local carrier: chaos poisoning and guard admission run on
         the *compact* ``(block, V)`` factors (same call sequence as the
@@ -1080,10 +1101,11 @@ class IncrementalEngine:
             if infn is not None:
                 # unguarded compact chain: mutate the touched rows in
                 # place — no padding, no staged program, no copy floor
-                self.views = infn(self.views, rows, B, V)
+                with obs.span("engine.dispatch"):
+                    self.views = infn(self.views, rows, B, V)
                 return self._rowlocal_epilogue(input_name, carrier, rank,
                                                int(rows.shape[0]), t0,
-                                               block, t_count)
+                                               block, t_count, stacked_rank)
         base = self.compiled.triggers[input_name].rank
         rank_bucket = rank if rank == base else batch_bucket(rank)
         if rank_bucket != rank:
@@ -1117,10 +1139,11 @@ class IncrementalEngine:
                 self.guard.on_abort(input_name, P0, V0, e.reason)
                 return self.views
         else:
-            self.views = fn(self.views, rows, B, V)
+            with obs.span("engine.dispatch"):
+                self.views = fn(self.views, rows, B, V)
         self._note_fallbacks(fn)
         return self._rowlocal_epilogue(input_name, carrier, rank_bucket, r,
-                                       t0, block, t_count)
+                                       t0, block, t_count, stacked_rank)
 
     def _note_fallbacks(self, fn) -> None:
         """Count this firing's Pallas applies that ran the XLA reference
@@ -1139,18 +1162,22 @@ class IncrementalEngine:
 
     def _rowlocal_epilogue(self, input_name: str, carrier: RowLocalCarrier,
                            rank: int, r: int, t0: float, block: bool,
-                           t_count: int) -> Dict[str, Array]:
+                           t_count: int, stacked_rank: Optional[int]
+                           ) -> Dict[str, Array]:
         """Shared accounting tail of a row-slab firing (staged or
         in-place): plan staleness, timed-sweep stats, firing counters,
-        and the planner's observed affected fraction."""
+        and the planner's observed affected fraction.  ``rank`` is the
+        rank fired (padded to its bucket on the staged path);
+        ``stacked_rank`` the batch's rank before re-compression, where
+        it had one."""
         if self.plan is not None:
             for up in self.compiled.triggers[input_name].updates:
                 self._accum_rank[up.view] = \
                     self._accum_rank.get(up.view, 0) + rank
         if block:
-            jax.block_until_ready(self.views)
+            with obs.span("engine.block"):
+                jax.block_until_ready(self.views)
             self.stats.trigger_seconds += time.perf_counter() - t0
-            self.stats.updates_timed += t_count
             self.stats.sweep_flops_timed += \
                 self._rowlocal_sweep_flops(input_name, rank, r)
         self.stats.updates_applied += t_count
@@ -1158,6 +1185,10 @@ class IncrementalEngine:
         self.stats.rowlocal_firings += 1
         if t_count > 1:
             self.stats.batches_applied += 1
+        self.stats.stacked_rank += (carrier.rank if stacked_rank is None
+                                    else stacked_rank)
+        self.stats.fired_rank += carrier.rank
+        self.stats.padded_rank += rank
         self._observe_firing(input_name, carrier.rank, t_count,
                              affected_fraction=carrier.affected_fraction())
         if self.guard is not None:
@@ -1199,9 +1230,8 @@ class IncrementalEngine:
                 and self._rowlocal_ok(input_name, probe)):
             self.stats.widened_carriers += \
                 sum(1 for c in live if c.kind == "row_local")
-            return self.apply_updates(input_name,
-                                      [c.factors() for c in live],
-                                      block=block)
+            return self._apply_updates(input_name,
+                                       [c.factors() for c in live], block)
         # row-local fast path: poison each member compactly (one chaos
         # gate per logical update — the same draw count as the dense
         # batched path), restack, optionally re-compress the compact
@@ -1219,16 +1249,18 @@ class IncrementalEngine:
         stacked = probe
         if (self.max_batch_rank is not None
                 and stacked.rank > self.max_batch_rank):
-            B2, V2 = recompress_factors(stacked.block, stacked.V,
-                                        max_rank=self.max_batch_rank,
-                                        tol=self.recompress_tol)
+            with obs.span("engine.recompress"):
+                B2, V2 = recompress_factors(stacked.block, stacked.V,
+                                            max_rank=self.max_batch_rank,
+                                            tol=self.recompress_tol)
             stacked = RowLocalCarrier(stacked.rows,
                                       np.asarray(B2, np.float32),
                                       np.asarray(V2, np.float32),
                                       stacked.n)
             self.stats.recompressions += 1
         return self._apply_rowlocal(input_name, stacked, block=block,
-                                    t_count=len(live), poisoned=True)
+                                    t_count=len(live), poisoned=True,
+                                    stacked_rank=probe.rank)
 
     # -- batched incremental path ---------------------------------------------
     def apply_updates(self, input_name: str,
@@ -1247,10 +1279,16 @@ class IncrementalEngine:
         if input_name not in self.compiled.triggers:
             raise KeyError(f"no trigger for input {input_name!r}; have "
                            f"{sorted(self.compiled.triggers)}")
-        updates = list(updates)
-        if any(isinstance(x, DeltaCarrier) for x in updates):
-            return self._apply_carrier_batch(input_name, updates,
-                                             block=block)
+        with obs.span("engine.apply_updates", request=True):
+            updates = list(updates)
+            if any(isinstance(x, DeltaCarrier) for x in updates):
+                return self._apply_carrier_batch(input_name, updates,
+                                                 block=block)
+            return self._apply_updates(input_name, updates, block)
+
+    def _apply_updates(self, input_name: str, updates: list,
+                       block: bool) -> Dict[str, Array]:
+        """The dense batched firing of :meth:`apply_updates`."""
         if self.chaos is not None:
             updates = [self.chaos.poison_update(u, v) for u, v in updates]
         if not updates:
@@ -1272,24 +1310,26 @@ class IncrementalEngine:
         # concat (and any device sync from jax-array factors) is part of
         # the batch cost — the guard's fast path fuses admission INTO
         # the concat the trigger needs anyway
-        P = Q = None
-        if self.guard is not None:
-            stacked = self.guard.admit_batch_stacked(input_name, updates)
-            if stacked is not None:
-                P, Q = stacked
-            else:
-                # careful walk: one poisoned update quarantines alone
-                # and the healthy remainder still batches
-                updates = self.guard.admit_batch(input_name, updates)
-                if not updates:
-                    return self.views
+        with obs.span("engine.stack"):
+            P = Q = None
+            if self.guard is not None:
+                stacked = self.guard.admit_batch_stacked(input_name, updates)
+                if stacked is not None:
+                    P, Q = stacked
+                else:
+                    # careful walk: one poisoned update quarantines alone
+                    # and the healthy remainder still batches
+                    updates = self.guard.admit_batch(input_name, updates)
+                    if not updates:
+                        return self.views
+            if P is None:
+                P, Q = stack_update_arrays(updates)
         t_count = len(updates)
-        if P is None:
-            P, Q = stack_update_arrays(updates)
         stacked_rank = P.shape[1]
         if self.max_batch_rank is not None and P.shape[1] > self.max_batch_rank:
-            P, Q = recompress_factors(P, Q, max_rank=self.max_batch_rank,
-                                      tol=self.recompress_tol)
+            with obs.span("engine.recompress"):
+                P, Q = recompress_factors(P, Q, max_rank=self.max_batch_rank,
+                                          tol=self.recompress_tol)
             self.stats.recompressions += 1
         P0, Q0 = P, Q  # pre-padding factors (what a rollback quarantines)
         bucket = batch_bucket(P.shape[1])
@@ -1307,14 +1347,17 @@ class IncrementalEngine:
         if self._tiers:
             self._cascade_accumulate(input_name, [(P0, Q0)])
         if block:
-            jax.block_until_ready(self.views)
+            with obs.span("engine.block"):
+                jax.block_until_ready(self.views)
             self.stats.trigger_seconds += time.perf_counter() - t0
-            self.stats.updates_timed += t_count
             self.stats.sweep_flops_timed += self._sweep_flops(input_name,
                                                               bucket)
         self.stats.updates_applied += t_count
         self.stats.triggers_fired += 1
         self.stats.batches_applied += 1
+        self.stats.stacked_rank += stacked_rank
+        self.stats.fired_rank += P0.shape[1]
+        self.stats.padded_rank += bucket
         self._observe_firing(input_name, stacked_rank, t_count)
         if self.guard is not None:
             self.guard.after_firing(self)

@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.codegen import evaluate, matmul, trigger_touched_views
+from repro.core.codegen import (evaluate, firing_name, matmul, named,
+                                trigger_touched_views)
 from repro.core.compiler import Trigger
 from repro.core.program import Program
 from repro.dist.sharding import auto_axes
@@ -122,7 +123,7 @@ def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
         return tuple(env[name] for name in written)
 
     if jit:
-        core = jax.jit(core)
+        core = jax.jit(named(core, firing_name(trigger, "sharded")))
 
     def run(views: Env, u: Array, v: Array) -> Env:
         new_vals = core(tuple(views[n] for n in written),

@@ -287,10 +287,11 @@ class EngineGuard:
                      screen_inputs: bool):
         import jax
         import jax.numpy as jnp
-        from repro.core.codegen import trigger_touched_views
+        from repro.core.codegen import (firing_name, named,
+                                        trigger_touched_views)
         inner = engine._batched_trigger_fn(input_name, bucket)
-        written, read_only = trigger_touched_views(
-            engine._bucket_trigger(input_name, bucket))
+        trigger = engine._bucket_trigger(input_name, bucket)
+        written, read_only = trigger_touched_views(trigger)
 
         # flat tuples across the jit boundary (the dict-pytree
         # round-trip costs tens of µs per dispatch — same reason
@@ -318,7 +319,7 @@ class EngineGuard:
             bad = jnp.stack([~ok_in, ok_in & ~ok_out])
             return new, nbad + bad.astype(jnp.int32)
 
-        core = jax.jit(core)
+        core = jax.jit(named(core, firing_name(trigger, "guarded")))
 
         def fused(views, u, v, nbad):
             new, nbad = core(tuple(views[n] for n in written),

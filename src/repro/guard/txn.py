@@ -69,7 +69,8 @@ def restore_snapshot(engine, snap: FiringSnapshot) -> None:
     engine._accum_rank = snap.accum_rank
     engine._stale = snap.stale
     for f in dataclasses.fields(type(engine.stats)):
-        setattr(engine.stats, f.name, getattr(snap.stats, f.name))
+        if f.name != "trigger_builds":  # a build stays in the trigger cache
+            setattr(engine.stats, f.name, getattr(snap.stats, f.name))
     if snap.cascade is not None:
         engine._cascade_restore(snap.cascade)
 

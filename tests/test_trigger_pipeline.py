@@ -269,8 +269,8 @@ def test_explicit_flush_all_inputs():
 
 
 def test_stats_timed_vs_untimed():
-    """trigger_seconds must pair with updates_timed, not updates_applied:
-    async firings are counted but never timed."""
+    """Async firings are counted but never timed; every firing, timed or
+    not, adds its rank to the rank counters."""
     build, inputs_fn, name, n, m = PROGRAMS["ols"]
     eng = IncrementalEngine(build())
     eng.initialize(inputs_fn())
@@ -279,10 +279,11 @@ def test_stats_timed_vs_untimed():
     eng.apply_update(name, *map(jnp.asarray, ups[1]), block=True)  # timed
     eng.apply_updates(name, [ups[2]], block=True)                  # timed
     assert eng.stats.updates_applied == 3
-    assert eng.stats.updates_timed == 2
     assert eng.stats.triggers_fired == 3
     assert eng.stats.trigger_seconds > 0.0
-    assert eng.stats.per_update_seconds() > 0.0
+    # three rank-1 firings: nothing to re-compress, bucket 1 each
+    assert (eng.stats.stacked_rank, eng.stats.fired_rank,
+            eng.stats.padded_rank) == (3, 3, 3)
 
 
 # -- serving-path contract ----------------------------------------------------
