@@ -15,7 +15,7 @@ from .factored import (DeltaCarrier, DeltaRep, DenseDelta, HStack,
                        LowRank, LowRankCarrier, NoOpCarrier,
                        RowLocalCarrier, as_carrier, detect_row_local,
                        pad_factors_to_rank, recompress_factors,
-                       row_delta_carrier, stack_carriers,
+                       row_delta_carrier, row_support, stack_carriers,
                        stack_update_arrays)
 from .delta import DeltaEnv, derive, derive_delta, IncrementalInverseError
 from .compiler import (Assign, CompiledProgram, DeltaView, Trigger,
@@ -39,7 +39,8 @@ __all__ = [
     "Program", "Statement", "dim",
     "DeltaRep", "DenseDelta", "HStack", "LowRank",
     "DeltaCarrier", "LowRankCarrier", "RowLocalCarrier", "NoOpCarrier",
-    "as_carrier", "detect_row_local", "row_delta_carrier", "stack_carriers",
+    "as_carrier", "detect_row_local", "row_delta_carrier", "row_support",
+    "stack_carriers",
     "pad_factors_to_rank", "recompress_factors", "stack_update_arrays",
     "DeltaEnv", "derive", "derive_delta", "IncrementalInverseError",
     "Assign", "CompiledProgram", "DeltaView", "Trigger", "ViewUpdate",

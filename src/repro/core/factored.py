@@ -340,9 +340,20 @@ def stack_update_arrays(updates: Sequence[Tuple["np.ndarray", "np.ndarray"]]
     return np.concatenate(us, axis=1), np.concatenate(vs, axis=1)
 
 
+def row_support(P: "np.ndarray") -> Optional["np.ndarray"]:
+    """The rows on which :func:`recompress_factors` re-compresses
+    ``P Qᵀ``: the indices of ``P``'s nonzero rows where there are fewer
+    of them than ``P`` has columns (and at least one); else ``None``, for
+    which re-compression QR-factors ``P`` whole.  One O(nK) scan."""
+    import numpy as np
+    rows = np.flatnonzero(np.any(P != 0, axis=1))
+    return rows if 0 < rows.size < P.shape[1] else None
+
+
 def recompress_factors(P: "np.ndarray", Q: "np.ndarray",
                        max_rank: Optional[int] = None,
-                       tol: float = 1e-7
+                       tol: float = 1e-7,
+                       rows: Optional["np.ndarray"] = None
                        ) -> Tuple["np.ndarray", "np.ndarray"]:
     """Re-compress a stacked factored delta ``P Qᵀ`` to minimal rank.
 
@@ -359,6 +370,18 @@ def recompress_factors(P: "np.ndarray", Q: "np.ndarray",
     will touch, which is what makes compaction pay before a rank-K
     trigger fires.  Singular values below ``tol · σ_max`` are dropped;
     ``max_rank`` caps the result (lossy beyond the numerical rank).
+
+    Where ``P`` is nonzero on only s < K rows S (:func:`row_support`;
+    a batch of rank-1 row updates has one-hot columns), ``P = E_S P_S``
+    with the selector ``E_S`` already orthonormal, so only ``Q P_Sᵀ``
+    needs a QR:
+
+        Q P_Sᵀ = Q_m R_m,   R_mᵀ = U Σ Vᵀ
+        P'[S] = U_r Σ_r (zero elsewhere),   Q' = Q_m V_r
+
+    Cost O(m K s + m s²): the same singular values under the same rule,
+    at the same float32 precision, and ``P'`` keeps ``P``'s row support.
+    ``rows`` passes a :func:`row_support` of ``P`` already taken.
     """
     import numpy as np
     P = np.asarray(P, dtype=np.float32)
@@ -366,14 +389,24 @@ def recompress_factors(P: "np.ndarray", Q: "np.ndarray",
     K = P.shape[1]
     if K != Q.shape[1]:
         raise ex.ShapeError(f"factor rank mismatch: {P.shape} vs {Q.shape}")
-    qp, rp = np.linalg.qr(P)           # (n, K), (K, K)
-    qq, rq = np.linalg.qr(Q)           # (m, K), (K, K)
-    uc, s, vct = np.linalg.svd(rp @ rq.T)
+    if rows is None:
+        rows = row_support(P)
+    if rows is not None:
+        qq, rq = np.linalg.qr(Q @ P[rows].T)   # (m, s), (s, s)
+        uc, s, vct = np.linalg.svd(rq.T)
+    else:
+        qp, rp = np.linalg.qr(P)           # (n, K), (K, K)
+        qq, rq = np.linalg.qr(Q)           # (m, K), (K, K)
+        uc, s, vct = np.linalg.svd(rp @ rq.T)
     r = int(np.sum(s > tol * (s[0] if s.size else 0.0)))
     r = max(1, r)
     if max_rank is not None:
         r = min(r, max_rank)
-    P2 = qp @ (uc[:, :r] * s[:r])      # (n, r)
+    if rows is not None:
+        P2 = np.zeros((P.shape[0], r), np.float32)
+        P2[rows] = uc[:, :r] * s[:r]   # (s, r) on the support
+    else:
+        P2 = qp @ (uc[:, :r] * s[:r])      # (n, r)
     Q2 = qq @ vct[:r].T                # (m, r)
     return P2.astype(np.float32), Q2.astype(np.float32)
 

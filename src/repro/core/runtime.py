@@ -42,7 +42,7 @@ from .compiler import (CompiledProgram, Trigger, batch_bucket,
                        compile_program)
 from .factored import (DeltaCarrier, LowRankCarrier, RowLocalCarrier,
                        as_carrier, pad_factors_to_rank, recompress_factors,
-                       stack_carriers, stack_update_arrays)
+                       row_support, stack_carriers, stack_update_arrays)
 from .program import Program
 
 Array = jax.Array
@@ -61,6 +61,7 @@ class EngineStats:
     trigger_seconds: float = 0.0
     batches_applied: int = 0
     recompressions: int = 0
+    support_recompressions: int = 0  # of those, on the row support
     reevals: int = 0
     reeval_seconds: float = 0.0
     plan_reevals: int = 0         # views re-evaluated inside planned firings
@@ -359,6 +360,17 @@ class IncrementalEngine:
         self._tier_base = {o: dict(base[o]) for o in base}
         self._tier_firings = dict(firings)
 
+    def _recompress(self, P, Q, max_rank: int):
+        """:func:`~repro.core.factored.recompress_factors` at the
+        engine's tolerance, counted, and counted again where it took the
+        row-support route."""
+        rows = row_support(P)
+        P, Q = recompress_factors(P, Q, max_rank=max_rank,
+                                  tol=self.recompress_tol, rows=rows)
+        self.stats.recompressions += 1
+        self.stats.support_recompressions += rows is not None
+        return P, Q
+
     def _cascade_accumulate(self, input_name: str, pairs,
                             defer_input: bool = False) -> None:
         """Append one admitted firing's (pre-padding) factors to every
@@ -386,13 +398,10 @@ class IncrementalEngine:
             if self.max_fold_rank is not None:
                 rank = sum(a.shape[1] for a, _ in fs)
                 if rank > self.max_fold_rank:
-                    P, Q = stack_update_arrays(fs)
-                    P, Q = recompress_factors(P, Q,
-                                              max_rank=self.max_fold_rank,
-                                              tol=self.recompress_tol)
+                    P, Q = self._recompress(*stack_update_arrays(fs),
+                                            self.max_fold_rank)
                     self._tier_factors[o][input_name] = \
                         [(np.asarray(P), np.asarray(Q))]
-                    self.stats.recompressions += 1
         self._maybe_fold()
 
     def _inputs_deferrable(self, input_name: str) -> bool:
@@ -1250,14 +1259,12 @@ class IncrementalEngine:
         if (self.max_batch_rank is not None
                 and stacked.rank > self.max_batch_rank):
             with obs.span("engine.recompress"):
-                B2, V2 = recompress_factors(stacked.block, stacked.V,
-                                            max_rank=self.max_batch_rank,
-                                            tol=self.recompress_tol)
+                B2, V2 = self._recompress(stacked.block, stacked.V,
+                                          self.max_batch_rank)
             stacked = RowLocalCarrier(stacked.rows,
                                       np.asarray(B2, np.float32),
                                       np.asarray(V2, np.float32),
                                       stacked.n)
-            self.stats.recompressions += 1
         return self._apply_rowlocal(input_name, stacked, block=block,
                                     t_count=len(live), poisoned=True,
                                     stacked_rank=probe.rank)
@@ -1328,9 +1335,7 @@ class IncrementalEngine:
         stacked_rank = P.shape[1]
         if self.max_batch_rank is not None and P.shape[1] > self.max_batch_rank:
             with obs.span("engine.recompress"):
-                P, Q = recompress_factors(P, Q, max_rank=self.max_batch_rank,
-                                          tol=self.recompress_tol)
-            self.stats.recompressions += 1
+                P, Q = self._recompress(P, Q, self.max_batch_rank)
         P0, Q0 = P, Q  # pre-padding factors (what a rollback quarantines)
         bucket = batch_bucket(P.shape[1])
         P, Q = pad_factors_to_rank(P, Q, bucket)

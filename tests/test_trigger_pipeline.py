@@ -17,7 +17,7 @@ import pytest
 from repro.apps.ols import build_ols_program
 from repro.core.compiler import batch_bucket, compile_batched_trigger
 from repro.core.factored import (pad_factors_to_rank, recompress_factors,
-                                 stack_update_arrays)
+                                 row_support, stack_update_arrays)
 from repro.core.iterative import matrix_powers
 from repro.core.runtime import IncrementalEngine, ReevalEngine, max_abs_diff
 from repro.data.updates import UpdateStream
@@ -161,6 +161,142 @@ def test_recompress_caps_rank(rng):
     P2, Q2 = recompress_factors(P, Q, tol=1e-4)
     assert P2.shape[1] == 1
     assert_close(P2 @ Q2.T, 8 * (u @ v.T), rtol=1e-3, atol=1e-3)
+
+
+def _recompress_full_qr(P, Q, max_rank=None, tol=1e-7):
+    """Re-compression by thin QR of both whole factors, as it was before
+    the row-support route: the reference for factors it does not take."""
+    qp, rp = np.linalg.qr(P)
+    qq, rq = np.linalg.qr(Q)
+    uc, s, vct = np.linalg.svd(rp @ rq.T)
+    r = int(np.sum(s > tol * (s[0] if s.size else 0.0)))
+    r = max(1, r)
+    if max_rank is not None:
+        r = min(r, max_rank)
+    P2 = qp @ (uc[:, :r] * s[:r])
+    Q2 = qq @ vct[:r].T
+    return P2.astype(np.float32), Q2.astype(np.float32)
+
+
+def _factors(case, rng, n=96, m=80):
+    """Stacked factors ``(P, Q)`` of one kind of batch."""
+    if case == "zipf_one_hot":      # rank-1 row updates, rows repeat
+        return stack_update_arrays(_updates(n, m, 48, seed=5, zipf=2.0))
+    if case == "sparse_multi_col":  # rank-3 updates of non-unit rows
+        rows = rng.choice(n, 10, replace=False)
+        ups = []
+        for _ in range(8):
+            u = np.zeros((n, 3), np.float32)
+            u[rng.choice(rows, 4, replace=False)] = rng.normal(size=(4, 3))
+            ups.append((u, rng.normal(size=(m, 3)).astype(np.float32)))
+        return stack_update_arrays(ups)
+    if case == "s_equals_k":        # as many nonzero rows as columns
+        P = np.zeros((n, 16), np.float32)
+        P[rng.choice(n, 16, replace=False)] = rng.normal(size=(16, 16))
+        return P, rng.normal(size=(m, 16)).astype(np.float32)
+    if case == "dense":
+        return (rng.normal(size=(n, 24)).astype(np.float32),
+                rng.normal(size=(m, 24)).astype(np.float32))
+    # "spectrum": 12 nonzero rows, K=32, singular values 2^-i, so both a
+    # tolerance and a cap cut at a clear gap
+    s, K = 12, 32
+    W = np.linalg.qr(rng.normal(size=(K, s)))[0]
+    U = np.linalg.qr(rng.normal(size=(s, s)))[0]
+    V = np.linalg.qr(rng.normal(size=(m, s)))[0]
+    P = np.zeros((n, K))
+    P[rng.choice(n, s, replace=False)] = U * 2.0 ** -np.arange(s) @ W.T
+    return P.astype(np.float32), (V @ W.T).astype(np.float32)
+
+
+@pytest.mark.parametrize("case,tol,max_rank", [
+    ("zipf_one_hot", 1e-7, None),
+    ("sparse_multi_col", 1e-7, None),
+    ("s_equals_k", 1e-7, None),
+    ("dense", 1e-7, None),
+    ("spectrum", 1e-2, None),       # the tolerance cuts at rank 7
+    ("spectrum", 1e-7, 5),          # the cap cuts below the rank, 12
+    ("zipf_one_hot", 1e-7, 4),      # the cap cuts a Zipf batch
+])
+def test_recompress_factors_row_support_route(case, tol, max_rank, rng):
+    """Factors nonzero on fewer rows than columns re-compress on those
+    rows: the same product and rank rule as a float64 SVD, and a left
+    factor zero off the support.  Other factors take the full QR route,
+    bit for bit as before."""
+    P, Q = _factors(case, rng)
+    K = P.shape[1]
+    support = np.flatnonzero(np.any(P != 0, axis=1))
+    rows = row_support(P)
+    assert (rows is not None) == (support.size < K)
+    if rows is not None:
+        assert np.array_equal(rows, support)
+    P2, Q2 = recompress_factors(P, Q, max_rank=max_rank, tol=tol)
+    assert P2.dtype == Q2.dtype == np.float32
+
+    # rank: the float64 SVD of P Qᵀ under the same tolerance and cap
+    exact = P.astype(np.float64) @ Q.astype(np.float64).T
+    U, s, Vt = np.linalg.svd(exact)
+    r = max(1, int(np.sum(s > tol * s[0])))
+    r = r if max_rank is None else min(r, max_rank)
+    assert P2.shape[1] == Q2.shape[1] == r
+    best = (U[:, :r] * s[:r]) @ Vt[:r]
+    got = P2.astype(np.float64) @ Q2.astype(np.float64).T
+    assert np.abs(got - best).max() <= 1e-5 * np.abs(exact).max()
+
+    off = np.setdiff1d(np.arange(P.shape[0]), support)
+    if rows is not None:
+        assert not P2[off].any()
+    else:
+        # the full QR leaves rounding off the support
+        assert np.abs(P2[off]).max(initial=0.0) <= 1e-6 * np.abs(P2).max()
+        B2, V2 = _recompress_full_qr(P, Q, max_rank=max_rank, tol=tol)
+        assert np.array_equal(P2, B2) and np.array_equal(Q2, V2)
+
+
+def _dense_batch(n, count, seed=1):
+    rng = np.random.default_rng(seed)
+    return [(rng.standard_normal((n, 1)).astype(np.float32) * 0.1,
+             rng.standard_normal((n, 1)).astype(np.float32) * 0.1)
+            for _ in range(count)]
+
+
+@pytest.mark.parametrize("guarded", [False, True])
+def test_zipf_batch_recompresses_on_row_support(guarded):
+    """A batch of one-hot Zipf row updates over the rank cap fires once,
+    re-compressed on its row support, and matches re-evaluation."""
+    from repro.guard import GuardConfig
+    build, inputs_fn, name, n, m = PROGRAMS["powers"]
+    ups = _updates(n, m, 64, seed=5, zipf=2.0)
+    distinct = len({int(np.flatnonzero(u)[0]) for u, _ in ups})
+    assert distinct <= 16   # under the cap: re-compression is lossless
+
+    bat = IncrementalEngine(build(), max_batch_rank=16,
+                            guard=GuardConfig() if guarded else None)
+    bat.initialize(inputs_fn())
+    bat.apply_updates(name, ups, block=True)
+    assert bat.stats.recompressions == 1
+    assert bat.stats.support_recompressions == 1
+    assert bat.stats.stacked_rank == 64
+    assert bat.stats.fired_rank == distinct
+
+    ree = ReevalEngine(build())
+    ree.initialize(inputs_fn())
+    for u, v in ups:
+        ree.apply_update(name, jnp.asarray(u), jnp.asarray(v))
+    # every view, A included: the output P8 alone moves by less than
+    # 1e-3 under a wrongly re-compressed batch of deltas this small
+    assert max_abs_diff(bat.views, ree.views) < 1e-3
+
+
+@pytest.mark.parametrize("guarded", [False, True])
+def test_dense_batch_recompresses_by_full_qr(guarded):
+    from repro.guard import GuardConfig
+    build, inputs_fn, name, n, _ = PROGRAMS["powers"]
+    eng = IncrementalEngine(build(), max_batch_rank=5,
+                            guard=GuardConfig() if guarded else None)
+    eng.initialize(inputs_fn())
+    eng.apply_updates(name, _dense_batch(n, 12), block=True)
+    assert eng.stats.recompressions == 1
+    assert eng.stats.support_recompressions == 0
 
 
 def test_batch_bucket():
