@@ -102,6 +102,20 @@ class Smoke:
             info(f"{phase}: peak_bytes_in_use "
                  f"{stats['peak_bytes_in_use'] / 2**30:.2f} GiB")
 
+    def mesh_peaks(self, devices) -> None:
+        """Each chip's peak after the row-sharded engine: the input goes
+        to its row blocks and the views are computed there, so no chip
+        (chip 0 least of all) holds a whole view."""
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devices]
+        if None in peaks:
+            return
+        info("four-chips peak_bytes_in_use per chip: " + ", ".join(
+            f"{p / 2**30:.2f}" for p in peaks) + " GiB")
+        spread = (max(peaks) - min(peaks)) / 2**30
+        self.expect(spread <= 0.5, f"four-chips peaks differ by "
+                                   f"{spread:.2f} GiB across chips")
+
     # -- update streams (host float64 mirror of the input) -----------------------
     def row_updates(self, rng, mirror, count, scale, rows=None):
         """Rank-1 row updates ``X[r] += d``, applied to the mirror too."""
@@ -374,7 +388,8 @@ class Smoke:
         A = np.asarray(MatrixPowers.synthesize(n, seed=self.seed)["A"])
         mesh = jax.make_mesh((4,), ("rows",), devices=jax.devices()[:4])
         runs = {}
-        for where, kw in (("1 chip", {}), ("4 chips", {"mesh": mesh})):
+        # the mesh engine first, so that each chip's peak is its own
+        for where, kw in (("4 chips", {"mesh": mesh}), ("1 chip", {})):
             eng = IncrementalEngine(matrix_powers(k=k, n=n), {"A": 1},
                                     max_batch_rank=8, flush_size=16,
                                     flush_age=3600.0, **kw)
@@ -385,6 +400,8 @@ class Smoke:
             self.drive_engine(eng, "A", mirror, rng, scale=0.3 / n ** 0.5)
             info(f"four-chips {where}: {time.perf_counter() - t0:.3f} s "
                  f"(includes compile)")
+            if kw:
+                self.mesh_peaks(jax.devices()[:4])
             runs[where] = eng.views
             del eng
         one, four = runs["1 chip"], runs["4 chips"]

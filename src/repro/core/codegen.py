@@ -128,16 +128,27 @@ def _eval_node(x: Expr, env: Env, binding, go) -> Array:
 
 def build_evaluator(program: Program,
                     binding: Optional[Dict[str, int]] = None,
-                    jit: bool = True) -> Callable[[Env], Env]:
-    """Full re-evaluation: returns {view name: value} for all statements."""
+                    jit: bool = True,
+                    constrain: Optional[Callable] = None
+                    ) -> Callable[[Env], Env]:
+    """Full re-evaluation: returns {view name: value} for all statements.
+
+    ``constrain`` is the sharding hook of the distributed path
+    (:mod:`repro.dist.ivm_shard`): it pins every input and every
+    statement's value where it is produced; without it the program is
+    staged as it always was."""
     binding = dict(program.dims if binding is None else binding)
 
     def run(inputs: Env) -> Env:
         env: Env = dict(inputs)
+        if constrain is not None:
+            env = {k: constrain(v) for k, v in env.items()}
         cache: Dict[int, Array] = {}
         out: Env = {}
         for st in program.statements:
             val = evaluate(st.expr, env, binding, cache)
+            if constrain is not None:
+                val = constrain(val)
             env[st.target.name] = val
             out[st.target.name] = val
         return out
@@ -708,7 +719,10 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
     every view ends at its exact post-update value either way.
 
     ``constrain`` / ``replicate`` are sharding hooks for the
-    distributed path (:mod:`repro.dist.ivm_shard`); identity when None.
+    distributed path (:mod:`repro.dist.ivm_shard`): ``constrain`` pins
+    the views, ``replicate`` the update factors and the sweeps' right
+    factors (:func:`repro.dist.ivm_shard.right_factors`); identity when
+    None.
     """
     binding = dict(program.dims if binding is None else binding)
     apply = Applier(apply_backend)
@@ -728,6 +742,7 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
     read_only = tuple(sorted(read))
     cst = constrain if constrain is not None else (lambda x: x)
     rep = replicate if replicate is not None else (lambda x: x)
+    right = {up.v for up in updates if up.kind == "lowrank"}
 
     def core(written_vals: Tuple[Array, ...], read_vals: Tuple[Array, ...],
              u: Array, v: Array) -> Tuple[Array, ...]:
@@ -739,7 +754,8 @@ def build_planned_trigger_fn(trigger: Trigger, program: Program,
         env[trigger.v_var.name] = rep(v)
         cache: Dict[int, Array] = {}
         for a in assigns:
-            env[a.name] = evaluate(a.expr, env, binding, cache)
+            val = evaluate(a.expr, env, binding, cache)
+            env[a.name] = rep(val) if a.name in right else val
         for up in updates:
             if up.kind == "lowrank":
                 env[up.view] = cst(apply(up.view, env[up.view], env[up.u],

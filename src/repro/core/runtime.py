@@ -7,9 +7,13 @@ firing (stacked factors, §6 batching); ``reevaluate`` is the paper's
 baseline strategy for comparison/validation.
 
 With ``mesh=`` the engine routes every trigger firing — per-update and
-batched — through the row-sharded apply (:mod:`repro.dist.ivm_shard`):
-views are placed row-sharded at initialize time and each firing is the
-§6 distributed trigger, numerically identical to the single-device path.
+batched — through the row-sharded apply (:mod:`repro.dist.ivm_shard`).
+``initialize`` first places each input in row blocks across the mesh,
+then evaluates every view there in one program whose outputs are pinned
+row-sharded, so no chip ever holds a whole view; re-evaluations
+(``refresh``, folds, ``reevaluate``) run that same program.  Each firing
+is the §6 distributed trigger, numerically identical to the
+single-device path.
 
 With ``plan=`` (:mod:`repro.plan`) every firing executes a cost-based
 **maintenance plan**: per view, factored delta propagation while it
@@ -95,6 +99,9 @@ class EngineStats:
     # trigger fns built (trigger-cache misses).  A rollback keeps it, as
     # it keeps the cache (guard.txn.restore_snapshot)
     trigger_builds: int = 0
+    # collective operand bytes of the compiled program, summed over
+    # committed row-sharded firings (mesh engines)
+    collective_bytes: int = 0
 
 
 class IncrementalEngine:
@@ -236,7 +243,13 @@ class IncrementalEngine:
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.stats = EngineStats()
-        self._evaluator = build_evaluator(self.program, self.binding, jit=jit)
+        if mesh is None:
+            self._evaluator = build_evaluator(self.program, self.binding,
+                                              jit=jit)
+        else:
+            from repro.dist.ivm_shard import build_distributed_evaluator
+            self._evaluator = build_distributed_evaluator(
+                self.program, mesh, jit=jit, axis=mesh_axis)
         # planned execution state (repro.plan)
         self.plan = None
         self.planner = None
@@ -436,7 +449,7 @@ class IncrementalEngine:
             self.views[input_name] = apply(
                 input_name, self.views[input_name], jnp.asarray(P),
                 jnp.asarray(Q))
-            self._note_fallbacks(apply)
+            self._note_firing(apply)
             stacked[input_name] = (P, Q, len(pairs))
             pairs.clear()
         return stacked
@@ -578,7 +591,7 @@ class IncrementalEngine:
                                           frozenset(), lazy)
             base = dict(self._tier_base[o])
             out = fn(base, np.asarray(Pb), np.asarray(Qb))
-            self._note_fallbacks(fn)
+            self._note_firing(fn)
             for name in sweep:
                 self.views[name] = out[name]
             self.stats.fold_sweeps += len(sweep)
@@ -845,7 +858,7 @@ class IncrementalEngine:
             fn = self._batched_trigger_fn(input_name, bucket)
             with obs.span("engine.dispatch"):
                 self.views = fn(self.views, P, Q)
-            self._note_fallbacks(fn)
+            self._note_firing(fn)
             if self.plan is not None:
                 for up in self.compiled.triggers[input_name].updates:
                     self._accum_rank[up.view] = \
@@ -854,7 +867,7 @@ class IncrementalEngine:
         fn = self._planned_trigger_fn(input_name, bucket, reeval, lazy)
         with obs.span("engine.dispatch"):
             self.views = fn(self.views, P, Q)
-        self._note_fallbacks(fn)
+        self._note_firing(fn)
         recomputed = set(fn.recomputes)
         # count only plan-DIRECTED re-evaluations; recomputed also holds
         # lazy views pulled into the recompute closure for exactness
@@ -876,10 +889,18 @@ class IncrementalEngine:
             self._fold(self._tiers[-1])
         if not self._stale:
             return self.views
-        for st in self.program.statements:
-            if st.target.name in self._stale:
-                self.views[st.target.name] = evaluate(st.expr, self.views,
-                                                      self.binding)
+        if self.mesh is not None:
+            # the row-sharded evaluator, never an eager product whose
+            # placement the compiler would pick
+            computed = self._evaluator({k: self.views[k]
+                                        for k in self.program.inputs})
+            for name in self._stale:
+                self.views[name] = computed[name]
+        else:
+            for st in self.program.statements:
+                if st.target.name in self._stale:
+                    self.views[st.target.name] = evaluate(
+                        st.expr, self.views, self.binding)
         if block:
             jax.block_until_ready(self.views)
         self._stale.clear()
@@ -887,18 +908,25 @@ class IncrementalEngine:
 
     # -- lifecycle -----------------------------------------------------------
     def initialize(self, inputs: Dict[str, Array]) -> Dict[str, Array]:
-        """Full evaluation of the program; materializes every view (placed
-        row-sharded when the engine runs on a mesh)."""
+        """Full evaluation of the program; materializes every view.
+
+        On a mesh the inputs go to their row blocks first, whether they
+        come from the host, from one device or already sharded, and the
+        views are computed there (see the module docstring)."""
         missing = set(self.program.inputs) - set(inputs)
         if missing:
             raise KeyError(f"missing inputs: {sorted(missing)}")
-        computed = self._evaluator(dict(inputs))
-        self.views = {**{k: jnp.asarray(v) for k, v in inputs.items()},
-                      **computed}
-        if self.mesh is not None:
-            from repro.dist.ivm_shard import shard_views
-            self.views = shard_views(self.views, self.mesh,
-                                     axis=self.mesh_axis)
+        with obs.span("engine.initialize", request=True):
+            if self.mesh is None:
+                inputs = {k: jnp.asarray(v) for k, v in inputs.items()}
+            else:
+                from repro.dist.ivm_shard import shard_views
+                with obs.span("engine.place"):
+                    inputs = shard_views(inputs, self.mesh,
+                                         axis=self.mesh_axis)
+            with obs.span("engine.evaluate"):
+                computed = self._evaluator(dict(inputs))
+        self.views = {**inputs, **computed}
         self._stale.clear()
         self._accum_rank.clear()
         self._cascade_rebase_all()
@@ -969,7 +997,7 @@ class IncrementalEngine:
                 u, v = np.asarray(u), np.asarray(v)
             with obs.span("engine.dispatch"):
                 self.views = fn(self.views, u, v)
-            self._note_fallbacks(fn)
+            self._note_firing(fn)
         else:
             self._fire(input_name, rank, u, v)
         if self._tiers:
@@ -1150,14 +1178,19 @@ class IncrementalEngine:
         else:
             with obs.span("engine.dispatch"):
                 self.views = fn(self.views, rows, B, V)
-        self._note_fallbacks(fn)
+        self._note_firing(fn)
         return self._rowlocal_epilogue(input_name, carrier, rank_bucket, r,
                                        t0, block, t_count, stacked_rank)
 
-    def _note_fallbacks(self, fn) -> None:
-        """Count this firing's Pallas applies that ran the XLA reference
-        (``fn.fallbacks``, see :class:`~repro.core.codegen.Applier`)."""
+    def _note_firing(self, fn) -> None:
+        """Count what this firing's program reports: its Pallas applies
+        that ran the XLA reference (``fn.fallbacks``, see
+        :class:`~repro.core.codegen.Applier`) and, for a row-sharded
+        firing, the collective bytes of its compiled program
+        (``fn.collective_bytes``, see
+        :func:`repro.dist.ivm_shard.build_distributed_trigger`)."""
         self.stats.pallas_fallbacks += len(getattr(fn, "fallbacks", ()))
+        self.stats.collective_bytes += getattr(fn, "collective_bytes", 0)
 
     def _rowlocal_inplace_fn(self, input_name: str) -> Optional[Callable]:
         """The in-place compact applier for ``input_name``'s trigger

@@ -421,7 +421,7 @@ class EngineGuard:
             self.stats.rollbacks += 1
             raise FiringAborted(repr(e), input_name, "execute") from e
         engine.views = out  # safe either way: bad firings self-selected out
-        engine._note_fallbacks(fn)
+        engine._note_firing(fn)
         self._pending.append((self._nbad, input_name, P, Q))
 
     def sync(self) -> None:

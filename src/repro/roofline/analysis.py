@@ -31,6 +31,7 @@ _TYPE_RE = re.compile(r"(pred|bf16|f16|f32|f64|s8|u8|s16|u16|s32|u32|s64|u64)"
                       r"\[([0-9,]*)\]")
 _GROUPS_RE = re.compile(r"replica_groups=\{?\{([0-9,]+)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
 
 
 def _shape_bytes(dtype: str, dims: str) -> int:
@@ -49,6 +50,10 @@ class CollectiveOp:
     group_size: int
     wire_bytes: float
     line: str = ""
+    # one collective may be printed in several computations (a TPU
+    # async collective fusion repeats it in each of its steps); the
+    # copies share this id
+    channel_id: Optional[int] = None
 
 
 @dataclass
@@ -91,7 +96,9 @@ def parse_collectives(hlo_text: str) -> CollectiveSummary:
     summary = CollectiveSummary()
     for line in hlo_text.splitlines():
         stripped = line.strip()
-        m = re.search(r"=\s*(\([^)]*\)|\S+)\s+"
+        # a tuple result may hold TPU layouts, ``{0,1:T(8,128)}``: one
+        # level of nested parentheses
+        m = re.search(r"=\s*(\((?:[^()]|\([^()]*\))*\)|\S+)\s+"
                       r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
                       r"collective-permute)(-start)?\(", stripped)
         if not m:
@@ -116,11 +123,13 @@ def parse_collectives(hlo_text: str) -> CollectiveSummary:
         # async -start results wrap (operand, result, …): prefer operands
         if operand_bytes == 0:
             operand_bytes = result_bytes
+        cm = _CHANNEL_RE.search(stripped)
         summary.ops.append(CollectiveOp(
             kind=kind, result_bytes=result_bytes,
             operand_bytes=operand_bytes, group_size=g,
             wire_bytes=_wire_bytes(kind, result_bytes, operand_bytes, g),
-            line=stripped[:160]))
+            line=stripped[:160],
+            channel_id=int(cm.group(1)) if cm else None))
     return summary
 
 

@@ -159,6 +159,211 @@ def test_planned_engine_multi_device_subprocess():
     assert res.returncode == 0, f"STDOUT:\n{res.stdout}\nSTDERR:\n{res.stderr}"
 
 
+# -- sharded initialize on a 4-way mesh ----------------------------------------
+
+# One child process with four virtual devices drives every check of the
+# mesh engine's placement, its agreement with one device and with a plain
+# reference, its collective counter and its initialize spans, and prints
+# what it saw; the tests below each read their part of it.
+_MESH4_N = 256
+_MESH4_SCRIPT = textwrap.dedent("""
+    import json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro import obs
+    from repro.core import IncrementalEngine
+    from repro.core.iterative import matrix_powers
+    from repro.roofline.analysis import parse_collectives
+
+    n = {n}
+    rng = np.random.default_rng(0)
+    A = (rng.normal(size=(n, n)) * 0.9 / np.sqrt(n)).astype(np.float32)
+    mesh = jax.make_mesh((4,), ("rows",))
+    HIGHEST = jax.lax.Precision.HIGHEST
+
+    def placement(views):
+        return {{k: [str(v.sharding.spec),
+                    sorted({{s.device.id for s in v.addressable_shards}}),
+                    sorted({{tuple(s.data.shape)
+                            for s in v.addressable_shards}})]
+                for k, v in views.items()}}
+
+    def rel(got, want):
+        want = np.asarray(want, np.float64)
+        return float(np.max(np.abs(np.asarray(got, np.float64) - want))
+                     / np.max(np.abs(want)))
+
+    def reference(a):
+        out, p = {{"A": a}}, jnp.asarray(a)
+        for name in ("P2", "P4", "P8", "P16"):
+            p = jnp.matmul(p, p, precision=HIGHEST)
+            out[name] = p
+        return out
+
+    def program():
+        return matrix_powers(k=16, n=n)
+
+    report = {{"initialize": {{}}}}
+    inputs = {{"host": A,
+              "one device": jax.device_put(A, jax.devices()[0]),
+              "sharded": jax.device_put(
+                  A, NamedSharding(mesh, PartitionSpec("rows", None)))}}
+    for origin, a in inputs.items():
+        eng = IncrementalEngine(program(), {{"A": 1}}, mesh=mesh)
+        rec = obs.Spans()
+        before = obs.install(rec)
+        eng.initialize({{"A": a}})
+        obs.install(before)
+        ref = reference(A)
+        staged = eng._evaluator.lower({{"A": eng.views["A"]}}).compile()
+        report["initialize"][origin] = {{
+            "placement": placement(eng.views),
+            "evaluate_out": sorted({{str(s.spec) for s in jax.tree.leaves(
+                staged.output_shardings)}}),
+            "rel_err": max(rel(eng.views[k], ref[k]) for k in ref),
+            "spans": [[r.name, r.id, r.parent]
+                      for r in rec.nodes(0.0, float("inf"))]}}
+
+    # firings: per-update and batched, against one device and the
+    # reference; the counter against each firing program's own text
+    one = IncrementalEngine(program(), {{"A": 1}})
+    one.initialize({{"A": A}})
+    mirror = A.astype(np.float64)
+    rises, parsed = [], []
+
+    largest = []
+
+    def expected(fn):
+        ops = {{}}
+        for op in parse_collectives(fn.executable.as_text()).ops:
+            ops.setdefault(op.channel_id or id(op), op)
+        largest.append(max(op.operand_bytes for op in ops.values()))
+        return sum(op.operand_bytes for op in ops.values())
+
+    for i in range(3):
+        row = int(rng.integers(n))
+        u = np.zeros((n, 1), np.float32)
+        u[row, 0] = 1.0
+        d = (rng.normal(size=(n, 1)) * 0.3 / np.sqrt(n)).astype(np.float32)
+        mirror[row] += d[:, 0]
+        c0 = eng.stats.collective_bytes
+        eng.apply_update("A", u, d, block=True)
+        one.apply_update("A", u, d, block=True)
+        rises.append(eng.stats.collective_bytes - c0)
+        parsed.append(expected(eng._trigger_fns["A"]))
+    batch = []
+    for row in (3, 17):
+        u = np.zeros((n, 1), np.float32)
+        u[row, 0] = 1.0
+        d = (rng.normal(size=(n, 1)) * 0.3 / np.sqrt(n)).astype(np.float32)
+        mirror[row] += d[:, 0]
+        batch.append((u, d))
+    c0 = eng.stats.collective_bytes
+    eng.apply_updates("A", batch, block=True)
+    one.apply_updates("A", batch, block=True)
+    rises.append(eng.stats.collective_bytes - c0)
+    parsed.append(expected(eng._batched_triggers[("A", 2)]))
+    ref = reference(mirror.astype(np.float32))
+    report["firings"] = {{
+        "placement": placement(eng.views),
+        "vs_one_device": max(rel(eng.views[k], one.views[k]) for k in ref),
+        "vs_reference": max(rel(eng.views[k], ref[k]) for k in ref),
+        "rises": rises, "parsed": parsed, "largest": largest}}
+
+    # re-evaluations on the mesh: a fold's and refresh's
+    eng._fold_reeval({{"P4", "P16"}})
+    folded = placement(eng.views)
+    fold_err = max(rel(eng.views[k], ref[k]) for k in ref)
+    eng._stale = {{"P2", "P8"}}
+    eng.refresh(block=True)
+    report["reeval"] = {{
+        "fold_placement": folded, "fold_rel_err": fold_err,
+        "refresh_placement": placement(eng.views),
+        "refresh_rel_err": max(rel(eng.views[k], ref[k]) for k in ref)}}
+    print(json.dumps(report))
+""")
+
+_VIEWS = ("A", "P2", "P4", "P8", "P16")
+_ROW_BLOCKS = ["PartitionSpec('rows', None)", [0, 1, 2, 3],
+               [[_MESH4_N // 4, _MESH4_N]]]
+
+
+@pytest.fixture(scope="module")
+def mesh4_report():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run(
+        [sys.executable, "-c", _MESH4_SCRIPT.format(n=_MESH4_N)], env=env,
+        capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, f"STDOUT:\n{res.stdout}\nSTDERR:\n{res.stderr}"
+    import json
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("origin", ["host", "one device", "sharded"])
+def test_mesh_initialize_places_every_view_in_row_blocks(mesh4_report,
+                                                         origin):
+    """Whether the input comes from the host, whole on one device or
+    already sharded, every view ends split 4 ways by rows: each of the
+    four devices holds one (n/4, n) block, none a whole view, and the
+    values are a plain float32 HIGHEST re-evaluation's."""
+    init = mesh4_report["initialize"][origin]
+    # computed there: the evaluator's outputs are pinned to row blocks
+    assert init["evaluate_out"] == [_ROW_BLOCKS[0]], init
+    for name in _VIEWS:
+        assert init["placement"][name] == _ROW_BLOCKS, (name, init)
+    assert init["rel_err"] < 1e-5, init["rel_err"]
+
+
+def test_mesh_initialize_spans(mesh4_report):
+    """``engine.initialize`` is the root; ``engine.place`` (inputs to
+    their shards) and ``engine.evaluate`` open under it."""
+    spans = mesh4_report["initialize"]["host"]["spans"]
+    by_name = {name: (sid, parent) for name, sid, parent in spans}
+    assert set(by_name) == {"engine.initialize", "engine.place",
+                            "engine.evaluate"}, spans
+    root, root_parent = by_name["engine.initialize"]
+    assert root_parent is None
+    assert by_name["engine.place"][1] == root
+    assert by_name["engine.evaluate"][1] == root
+
+
+def test_mesh_firings_agree_with_one_device_and_reference(mesh4_report):
+    fired = mesh4_report["firings"]
+    for name in _VIEWS:
+        assert fired["placement"][name] == _ROW_BLOCKS, (name, fired)
+    assert fired["vs_one_device"] < 1e-5, fired
+    assert fired["vs_reference"] < 1e-5, fired
+
+
+def test_mesh_collective_bytes_count_each_firing_program(mesh4_report):
+    """``EngineStats.collective_bytes`` rises on each firing by the
+    collective operand bytes that ``parse_collectives`` reads from the
+    compiled text of the program that firing ran (each collective once).
+    Only skinny n×r factors cross: a rank-1 firing moves less than half
+    of one view's shard (n·n·4/4 bytes), and no collective of any firing
+    does.  (At n=256 the factors of five levels are a fair share of n;
+    at n=32768 the same count is about 3.7 MiB against a 1 GiB shard.)"""
+    fired = mesh4_report["firings"]
+    assert fired["rises"] == fired["parsed"], fired
+    half_shard = _MESH4_N * _MESH4_N * 4 // 4 // 2
+    assert all(0 < r < half_shard for r in fired["rises"][:3]), fired
+    assert max(fired["largest"]) < half_shard, fired
+
+
+def test_mesh_fold_and_refresh_keep_row_blocks(mesh4_report):
+    """A fold's re-evaluation and ``refresh()`` run the row-sharded
+    evaluator: the views stay in row blocks and stay exact."""
+    reeval = mesh4_report["reeval"]
+    for key in ("fold_placement", "refresh_placement"):
+        for name in _VIEWS:
+            assert reeval[key][name] == _ROW_BLOCKS, (key, name, reeval)
+    assert reeval["fold_rel_err"] < 1e-5, reeval
+    assert reeval["refresh_rel_err"] < 1e-5, reeval
+
+
 # -- cost-model-driven auto-flush ---------------------------------------------
 
 

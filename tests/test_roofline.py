@@ -92,6 +92,28 @@ def test_collective_parse_on_real_psum():
     assert w.collective_wire == 0.0
 
 
+# lines as a v5e 2x2 compile prints them: a tuple result whose layouts
+# hold parentheses, and one all-gather repeated in two steps of an async
+# collective fusion under one channel id
+_TPU_HLO = """
+  %all-reduce.13 = (f32[32768,2]{0,1:T(2,128)S(1)}, f32[2,2]{1,0:T(2,128)}) all-reduce(%fusion.2, %copy-done.34), channel_id=11, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.2.clone
+  %all-gather.55 = f32[32768,2]{0,1:T(2,128)} all-gather(%param_0.63), channel_id=8, replica_groups=[1,4]<=[4], dimensions={0}
+  %all-gather.57 = f32[32768,2]{0,1:T(2,128)S(1)} all-gather(%param_0.67), channel_id=8, replica_groups=[1,4]<=[4], dimensions={0}
+"""
+
+
+def test_collective_parse_tpu_tuple_layouts_and_channels():
+    from repro.dist.ivm_shard import collective_bytes
+    from repro.roofline.analysis import parse_collectives
+    ops = parse_collectives(_TPU_HLO).ops
+    assert [(o.kind, o.channel_id) for o in ops] == [
+        ("all-reduce", 11), ("all-gather", 8), ("all-gather", 8)]
+    assert ops[0].operand_bytes == 32768 * 2 * 4 + 2 * 2 * 4
+    assert ops[0].group_size == 4
+    # the repeated all-gather counts once
+    assert collective_bytes(_TPU_HLO) == ops[0].operand_bytes + 32768 * 2 * 4
+
+
 def test_model_flops_estimates_positive():
     from repro.configs import ARCHS, SHAPES, shape_applicable
     from repro.roofline.analysis import (model_bytes_estimate,
