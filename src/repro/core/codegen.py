@@ -9,6 +9,9 @@ Backends for the rank-k apply (``M += U Vᵀ``) are pluggable
   - "xla": plain jnp (default everywhere),
   - "pallas": the VMEM-tiled TPU kernel from ``repro.kernels.rank_update``
     (interpret-mode on CPU; the kernel is the TPU hot path).
+On a TPU, a written view whose factor products the trigger takes at a
+small rank is instead applied and projected in one pass
+(:func:`fused_view_passes`), whichever the backend.
 """
 
 from __future__ import annotations
@@ -16,6 +19,8 @@ from __future__ import annotations
 import functools
 import re
 import warnings
+from collections import Counter
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -191,6 +196,114 @@ class Applier:
             on_fallback=lambda why: self.fallbacks.__setitem__(name, why))
 
 
+# Delta ranks at which a view's apply and its factor products share one
+# pass.  On a v5e the pass beat XLA's staging of the same three results
+# at every rank from 1 (where XLA also reads the view once) to 8; at 16
+# its float32 VPU work outlasts XLA's three passes, and wider deltas
+# belong on the MXU (PERF.md §6).
+FUSED_PASS_RANKS = (1, 8)
+
+
+def on_tpu() -> bool:
+    """Whether the default backend is a TPU, the only place the
+    apply-and-project pass is staged (interpreted elsewhere, it is a
+    test tool, not a path)."""
+    return jax.default_backend() == "tpu"
+
+
+@dataclass(frozen=True)
+class FusedPass:
+    """A written view applied and projected in one pass: ``view += u vᵀ``,
+    and ``px = view·X``, ``pty = viewᵀ·Y`` (``MatMul`` nodes of the
+    assigns, or ``None``) taken on the old view before assign ``at``,
+    the first that reads one of them."""
+
+    view: str
+    u: str
+    v: str
+    at: int
+    px: Optional[Expr]
+    pty: Optional[Expr]
+
+
+def _product_of(x: Expr) -> Optional[Tuple[str, str]]:
+    """``(view, "x")`` for ``MatMul(view, ·)`` and ``(view, "y")`` for
+    ``MatMul(viewᵀ, ·)``, where ``view`` is a variable."""
+    if not isinstance(x, ex.MatMul):
+        return None
+    lhs = x.lhs
+    if isinstance(lhs, ex.Var):
+        return lhs.name, "x"
+    if isinstance(lhs, ex.Transpose) and isinstance(lhs.operand, ex.Var):
+        return lhs.operand.name, "y"
+    return None
+
+
+def fused_view_passes(trigger: Trigger, binding: Dict[str, int]
+                      ) -> Tuple[FusedPass, ...]:
+    """The written views of ``trigger`` to apply through
+    :func:`repro.kernels.ops.apply_project`, in trigger order.
+
+    On a TPU, a view qualifies when it is the target of one low-rank
+    ``+=`` and the big operand of one ``view·X`` and/or one ``viewᵀ·Y``
+    in the assigns, where the update's factors and ``X``, ``Y`` are
+    skinny values that do not read the view and exist before the first
+    assign that reads a product; when the update's rank and the
+    products' widths lie in :data:`FUSED_PASS_RANKS`; and when a
+    blocking of the view fits VMEM.  In matrix powers, ``P_j``'s
+    products are taken against its own ``dU_j``/``dV_j``."""
+    if not on_tpu():
+        return ()
+    from repro.kernels.ops import apply_project_blocks
+    # every product node, once, with the first assign that reads it
+    products: Dict[str, Dict[str, List[Tuple[int, Expr]]]] = {}
+    seen = set()
+    for idx, a in enumerate(trigger.assigns):
+        stack = [a.expr]
+        while stack:
+            x = stack.pop()
+            if id(x) in seen:
+                continue
+            seen.add(id(x))
+            hit = _product_of(x)
+            if hit is not None:
+                products.setdefault(hit[0], {"x": [], "y": []})[
+                    hit[1]].append((idx, x))
+            stack.extend(x.children)
+    order = {a.name: i for i, a in enumerate(trigger.assigns)}
+    shapes = {a.name: a.expr.shape for a in trigger.assigns}
+    for var in (trigger.u_var, trigger.v_var):
+        shapes[var.name] = var.shape
+    targets = Counter(up.view for up in trigger.updates)
+    lo, hi = FUSED_PASS_RANKS
+    out = []
+    for up in trigger.updates:
+        found = products.get(up.view)
+        if (up.kind != "lowrank" or targets[up.view] > 1 or found is None
+                or len(found["x"]) > 1 or len(found["y"]) > 1):
+            continue
+        at = min(idx for side in found.values() for idx, _ in side)
+        px, pty = (found[s][0][1] if found[s] else None for s in "xy")
+        operands = [e.rhs for e in (px, pty) if e is not None]
+        needed = {up.u, up.v}.union(*(e.free_vars() for e in operands))
+        if any(order.get(name, -1) >= at for name in needed):
+            continue
+        if any(_expr_refs(e, {up.view}) for e in operands):
+            continue
+        widths = [_dim(shapes[up.u][1], binding)]
+        widths += [_dim(e.shape[1], binding) for e in operands]
+        if not all(lo <= w <= hi for w in widths):
+            continue
+        big = (px.lhs if px is not None else pty.lhs.operand).shape
+        kx = _dim(px.rhs.shape[1], binding) if px is not None else 0
+        ky = _dim(pty.rhs.shape[1], binding) if pty is not None else 0
+        if apply_project_blocks(_dim(big[0], binding), _dim(big[1], binding),
+                                widths[0], kx, ky) is None:
+            continue
+        out.append(FusedPass(up.view, up.u, up.v, at, px, pty))
+    return tuple(out)
+
+
 @functools.lru_cache(maxsize=256)
 def _finite_check_jit(names: Tuple[str, ...]) -> Callable:
     def check(views: Env) -> Array:
@@ -267,12 +380,18 @@ def build_trigger_fn(trigger: Trigger, program: Program,
     every firing).  With ``donate=True`` the written views' buffers are
     donated, so the update is genuinely in-place on device; read-only
     views are never donated (callers may hold references).
+
+    The views :func:`fused_view_passes` picks are applied and projected
+    in one pass each, run before the first assign that reads one of
+    their products; the products enter the CSE cache, so the assigns
+    take them as they stand.  ``run.fused_views`` names those views.
     """
     binding = dict(program.dims if binding is None else binding)
     apply = Applier(apply_backend)
     written, read_only = trigger_touched_views(trigger)
     if donate:
         _warn_donation_ignored()
+    passes = fused_view_passes(trigger, binding)
 
     def core(written_vals: Tuple[Array, ...], read_vals: Tuple[Array, ...],
              u: Array, v: Array) -> Tuple[Array, ...]:
@@ -281,10 +400,16 @@ def build_trigger_fn(trigger: Trigger, program: Program,
         env[trigger.u_var.name] = u
         env[trigger.v_var.name] = v
         cache: Dict[int, Array] = {}
-        for a in trigger.assigns:
+        applied: Env = {}
+        for i, a in enumerate(trigger.assigns):
+            for fp in passes:
+                if fp.at == i:
+                    _run_fused_pass(fp, env, binding, cache, applied, donate)
             env[a.name] = evaluate(a.expr, env, binding, cache)
         for up in trigger.updates:
-            if up.kind == "lowrank":
+            if up.view in applied:
+                env[up.view] = applied[up.view]
+            elif up.kind == "lowrank":
                 env[up.view] = apply(up.view, env[up.view], env[up.u],
                                      env[up.v])
             else:
@@ -302,7 +427,23 @@ def build_trigger_fn(trigger: Trigger, program: Program,
         return views
 
     run.fallbacks = apply.fallbacks
+    run.fused_views = tuple(fp.view for fp in passes)
     return run
+
+
+def _run_fused_pass(fp: FusedPass, env: Env, binding: Dict[str, int],
+                    cache: Dict[int, Array], applied: Env,
+                    donate: bool) -> None:
+    """One apply-and-project pass: the new view goes to ``applied`` (the
+    assigns still read the old one), the products to the CSE cache."""
+    from repro.kernels import ops as rk_ops
+    x, y = (None if e is None else evaluate(e.rhs, env, binding, cache)
+            for e in (fp.px, fp.pty))
+    applied[fp.view], px, pty = rk_ops.apply_project(
+        env[fp.view], env[fp.u], env[fp.v], x, y, alias=donate)
+    for node, val in ((fp.px, px), (fp.pty, pty)):
+        if node is not None:
+            cache[id(node)] = val
 
 
 # ---------------------------------------------------------------------------

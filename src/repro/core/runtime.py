@@ -102,6 +102,9 @@ class EngineStats:
     # collective operand bytes of the compiled program, summed over
     # committed row-sharded firings (mesh engines)
     collective_bytes: int = 0
+    # views applied and projected in one pass (codegen.fused_view_passes),
+    # summed over committed firings
+    fused_view_passes: int = 0
 
 
 class IncrementalEngine:
@@ -1185,11 +1188,14 @@ class IncrementalEngine:
     def _note_firing(self, fn) -> None:
         """Count what this firing's program reports: its Pallas applies
         that ran the XLA reference (``fn.fallbacks``, see
-        :class:`~repro.core.codegen.Applier`) and, for a row-sharded
-        firing, the collective bytes of its compiled program
+        :class:`~repro.core.codegen.Applier`), its views applied in one
+        pass with their products (``fn.fused_views``, see
+        :func:`~repro.core.codegen.fused_view_passes`) and, for a
+        row-sharded firing, the collective bytes of its compiled program
         (``fn.collective_bytes``, see
         :func:`repro.dist.ivm_shard.build_distributed_trigger`)."""
         self.stats.pallas_fallbacks += len(getattr(fn, "fallbacks", ()))
+        self.stats.fused_view_passes += len(getattr(fn, "fused_views", ()))
         self.stats.collective_bytes += getattr(fn, "collective_bytes", 0)
 
     def _rowlocal_inplace_fn(self, input_name: str) -> Optional[Callable]:

@@ -329,6 +329,7 @@ class EngineGuard:
             return views, nbad
 
         fused.fallbacks = getattr(inner, "fallbacks", {})
+        fused.fused_views = getattr(inner, "fused_views", ())
         return (fused, written)
 
     def fire(self, engine, input_name: str, bucket: int, P, Q,

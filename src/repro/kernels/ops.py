@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from .apply_project import apply_project_pallas
 from .dual_matmul import dual_matmul_pallas
 from .flash_attention import flash_attention_pallas
 from .flash_decode import flash_decode_pallas
@@ -95,13 +96,14 @@ def vmem_bytes(blocks: Sequence[Sequence[int]],
 
 
 def _fit_blocks(n: int, p: int, footprint: Callable[[int, int], int],
-                cap: int = 512) -> Optional[Tuple[int, int]]:
+                cap: int = 512, budget: int = VMEM_BUDGET
+                ) -> Optional[Tuple[int, int]]:
     """Row block ``bm`` (8-aligned) and lane block ``bn`` (128-aligned)
-    of an ``(n, p)`` array whose ``footprint(bm, bn)`` fits the budget,
+    of an ``(n, p)`` array whose ``footprint(bm, bn)`` fits ``budget``,
     shrinking the larger one first; ``None`` when nothing fits."""
     bm = _pick_block(n, cap, SUBLANE)
     bn = _pick_block(p, cap, LANE)
-    while footprint(bm, bn) > VMEM_BUDGET:
+    while footprint(bm, bn) > budget:
         sm = _shrink_block(n, bm, SUBLANE)
         sn = _shrink_block(p, bn, LANE)
         if sm is not None and (bm >= bn or sn is None):
@@ -254,6 +256,47 @@ def dual_matmul(a: jax.Array, u: jax.Array, v: jax.Array,
         return ref.dual_matmul(a, u, v)
     return dual_matmul_pallas(a, u, v, bm=blocks[0], bn=blocks[1],
                               interpret=interpret_mode(interpret))
+
+
+# The apply-and-project pass takes tiles up to 1024²: at rank 8 its VPU
+# work per element is the largest of any kernel here, and 1024² tiles
+# ran a 1 GiB view 0.7–0.9 ms faster than 512² on a v5e (PERF.md §6).
+# Mosaic is granted APPLY_PROJECT_VMEM of scoped VMEM for them, of which
+# the pipelined blocks may take three quarters.
+APPLY_PROJECT_VMEM = 48 * 1024 * 1024
+
+
+def apply_project_blocks(n: int, m: int, k: int, kx: int, ky: int
+                         ) -> Optional[Tuple[int, int]]:
+    """``(bm, bn)`` tile of a ``(n, m)`` view for :func:`apply_project`
+    at update rank ``k`` and product widths ``kx``, ``ky`` (0 for a
+    product not taken): the old and new tiles, the factor blocks, the
+    ``P·X`` block and the whole ``(Pᵀ·Y)ᵀ``, plus two tile-sized
+    temporaries of the body; ``None`` when nothing fits."""
+    return _fit_blocks(n, m, lambda bm, bn: vmem_bytes(
+        [(bm, bn), (bm, k), (k, bn), (kx, bn), (bm, ky), (bm, bn),
+         (bm, kx), (m // bn, ky, bn)], scratch=[(bm, bn), (bm, bn)]),
+        cap=1024, budget=APPLY_PROJECT_VMEM * 3 // 4)
+
+
+def apply_project(p: jax.Array, u: jax.Array, v: jax.Array,
+                  x: Optional[jax.Array] = None,
+                  y: Optional[jax.Array] = None, *, alias: bool = False,
+                  interpret: Optional[bool] = None):
+    """``(p + u @ v.T, p @ x, p.T @ y)`` in one HBM pass over ``p``, the
+    products on the old ``p``; ``x`` or ``y`` may be ``None``.  ``alias``
+    writes the new view over ``p``'s buffer (only for a donated view).
+    The XLA reference when no blocking fits VMEM."""
+    n, m = p.shape
+    kx = 0 if x is None else x.shape[1]
+    ky = 0 if y is None else y.shape[1]
+    blocks = apply_project_blocks(n, m, u.shape[1], kx, ky)
+    if blocks is None:
+        return ref.apply_project(p, u, v, x, y)
+    return apply_project_pallas(p, u, v, x, y, bm=blocks[0], bn=blocks[1],
+                                alias=alias,
+                                interpret=interpret_mode(interpret),
+                                vmem_limit=APPLY_PROJECT_VMEM)
 
 
 def sherman_morrison_delta(w: jax.Array, u: jax.Array, v: jax.Array,

@@ -25,6 +25,16 @@ def dual_matmul(a: jax.Array, u: jax.Array, v: jax.Array
     return a @ u, a.T @ v
 
 
+def apply_project(p: jax.Array, u: jax.Array, v: jax.Array, x, y):
+    """Oracle for kernels.apply_project: ``(p + u vᵀ, p x, pᵀ y)`` in full
+    float32, since ``ops.apply_project`` falls back to it; ``None`` for a
+    product whose factor is ``None``."""
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+    return (p + mm(u, v.T), None if x is None else mm(p, x),
+            None if y is None else mm(p.T, y))
+
+
 def sherman_morrison_delta(w: jax.Array, u: jax.Array, v: jax.Array
                            ) -> Tuple[jax.Array, jax.Array]:
     """Oracle for the fused SM delta: Δ(E⁻¹) = L Rᵀ (paper §4.1)."""

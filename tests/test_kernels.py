@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import ops, ref
 from repro.kernels.rank_update import rank_update_pallas
 from repro.kernels.dual_matmul import dual_matmul_pallas
+from repro.kernels.apply_project import apply_project_pallas
 
 from conftest import assert_close
 
@@ -88,6 +89,81 @@ def test_sherman_morrison_fused(rng):
     # applying the delta gives the true new inverse
     from repro.core import sherman_morrison
     assert_close(w + l1 @ r1.T, sherman_morrison(w, u, v), rtol=1e-3)
+
+
+def _apply_project_case(rng, n, m, k, kx=None, ky=None):
+    kx = k if kx is None else kx
+    ky = k if ky is None else ky
+    f32 = lambda *shape: np.asarray(rng.normal(size=shape), np.float32)
+    return f32(n, m), f32(n, k), f32(m, k), f32(m, kx), f32(n, ky)
+
+
+def _assert_apply_project(got, p, u, v, x, y):
+    """Each result within 1e-5 of its float64 value, relative to the
+    largest entry of that value."""
+    p64, u64, v64 = (a.astype(np.float64) for a in (p, u, v))
+    want = (p64 + u64 @ v64.T,
+            None if x is None else p64 @ x.astype(np.float64),
+            None if y is None else p64.T @ y.astype(np.float64))
+    for g, w in zip(got, want):
+        if w is None:
+            assert g is None
+            continue
+        g = np.asarray(g, np.float64)
+        assert g.shape == w.shape
+        assert np.abs(g - w).max() <= 1e-5 * np.abs(w).max()
+
+
+@pytest.mark.parametrize("n,m", [(128, 128), (64, 256), (256, 128)])
+@pytest.mark.parametrize("k", [1, 2, 4, 8])
+def test_apply_project_shapes(n, m, k, rng):
+    p, u, v, x, y = _apply_project_case(rng, n, m, k)
+    _assert_apply_project(ops.apply_project(p, u, v, x, y), p, u, v, x, y)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4, 8])
+def test_apply_project_explicit_blocks(k, rng):
+    p, u, v, x, y = _apply_project_case(rng, 128, 256, k)
+    for bm, bn in ((8, 128), (32, 256), (64, 128), (128, 256)):
+        got = apply_project_pallas(p, u, v, x, y, bm=bm, bn=bn,
+                                   alias=False, interpret=True)
+        _assert_apply_project(got, p, u, v, x, y)
+
+
+def test_apply_project_one_product_and_other_widths(rng):
+    """Either product alone, and product widths other than the
+    update's rank."""
+    p, u, v, x, y = _apply_project_case(rng, 64, 256, 2, kx=3, ky=5)
+    for xx, yy in ((x, None), (None, y), (x, y)):
+        _assert_apply_project(ops.apply_project(p, u, v, xx, yy),
+                              p, u, v, xx, yy)
+
+
+def test_apply_project_falls_back_to_reference(rng):
+    """A prime width has no aligned divisor and its whole block
+    overflows VMEM: the XLA reference answers instead."""
+    n = 3001
+    assert ops.apply_project_blocks(n, n, 2, 2, 2) is None
+    p, u, v, x, y = _apply_project_case(rng, n, n, 2)
+    got = ops.apply_project(p, u, v, x, y)
+    _assert_apply_project(got, p, u, v, x, y)
+    for g, w in zip(got, ref.apply_project(p, u, v, x, y)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_apply_project_leaves_undonated_input(rng):
+    """Without ``alias`` the new view is a fresh buffer: the input view
+    keeps its old values, eagerly and inside a jitted program."""
+    p, u, v, x, y = _apply_project_case(rng, 128, 256, 4)
+    view = jnp.asarray(p)
+    got = ops.apply_project(view, u, v, x, y)
+    np.testing.assert_array_equal(np.asarray(view), p)
+    _assert_apply_project(got, p, u, v, x, y)
+    jitted = jax.jit(lambda view, u, v, x, y: ops.apply_project(
+        view, u, v, x, y))
+    got = jitted(view, u, v, x, y)
+    np.testing.assert_array_equal(np.asarray(view), p)
+    _assert_apply_project(got, p, u, v, x, y)
 
 
 @pytest.mark.parametrize("h,hkv,d,s,extra", [

@@ -113,3 +113,36 @@ def test_pallas_trigger_firing_compiles(one_chip, monkeypatch):
                           *[(n, n)] * len(names))
     assert text.count("tpu_custom_call") >= len(names)
     assert run.fallbacks == {}
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_apply_project_compiles(one_chip, k):
+    assert ops.apply_project_blocks(WIDE, WIDE, k, k, k) is not None
+    text = _compiled_text(
+        lambda p, u, v, x, y: ops.apply_project(p, u, v, x, y,
+                                                interpret=False),
+        one_chip, (WIDE, WIDE), *[(WIDE, k)] * 4)
+    assert "tpu_custom_call" in text
+
+
+def test_fused_trigger_firing_compiles(one_chip, monkeypatch):
+    """A rank-1 matrix-powers firing at n=16384 as a TPU stages it: one
+    apply-and-project kernel per view the selection picks, and XLA's
+    apply for the top view."""
+    from repro.core import codegen
+    from repro.core.compiler import compile_program
+    from repro.core.iterative import matrix_powers
+    monkeypatch.setattr(codegen, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "interpret_mode", lambda interpret=None: False)
+    compiled = compile_program(matrix_powers(k=16, n=WIDE), {"A": 1})
+    run = codegen.build_trigger_fn(compiled.triggers["A"], compiled.program,
+                                   jit=False)
+    assert {"P2", "P4", "P8"} <= set(run.fused_views)
+    names = ["A", "P2", "P4", "P8", "P16"]
+
+    def fire(u, v, *views):
+        return run(dict(zip(names, views)), u, v)
+
+    text = _compiled_text(fire, one_chip, (WIDE, 1), (WIDE, 1),
+                          *[(WIDE, WIDE)] * len(names))
+    assert text.count("tpu_custom_call") == len(run.fused_views)

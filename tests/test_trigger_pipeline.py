@@ -531,3 +531,117 @@ def test_pallas_fallbacks_counted(path):
     assert ref_eng.stats.pallas_fallbacks == 0
     for name in ("X", "Y1", "Y2"):
         assert_close(eng.views[name], ref_eng.views[name])
+
+
+# -- one pass per view: apply and products (codegen.fused_view_passes) -------
+
+# each view's delta rank in a rank-1 matrix-powers firing; the top view
+# (P16) is the big operand of no product
+POWERS_RANK1 = {"A": 1, "P2": 2, "P4": 4, "P8": 8}
+
+
+def _on_tpu(monkeypatch, yes: bool = True) -> None:
+    from repro.core import codegen
+    monkeypatch.setattr(codegen, "on_tpu", lambda: yes)
+
+
+def _fused(trigger, dims):
+    from repro.core.codegen import fused_view_passes
+    return [fp.view for fp in fused_view_passes(trigger, dims)]
+
+
+def _fused_rank1():
+    from repro.core.codegen import FUSED_PASS_RANKS
+    lo, hi = FUSED_PASS_RANKS
+    return [v for v, r in POWERS_RANK1.items() if lo <= r <= hi]
+
+
+def test_fused_pass_selection(monkeypatch):
+    """On a TPU a rank-1 matrix-powers firing applies P2, P4 and P8 in
+    one pass with their products; a rank-64 bucket, a trigger whose
+    views are each the big operand of two products (OLS through
+    Woodbury), one whose products read only inputs (the feature chain),
+    and any other backend take none."""
+    from repro.core.compiler import compile_program
+    compiled = compile_program(matrix_powers(k=16, n=256), {"A": 1})
+    trig, dims = compiled.triggers["A"], compiled.program.dims
+    _on_tpu(monkeypatch)
+    assert {"P2", "P4", "P8"} <= set(_fused_rank1())
+    assert _fused(trig, dims) == _fused_rank1()
+    assert _fused(compile_batched_trigger(compiled, "A", 64), dims) == []
+    ols = compile_program(build_ols_program(96, 48, 1), {"X": 1})
+    assert _fused(ols.triggers["X"], ols.program.dims) == []
+    chain = compile_program(_prime_chain(64), {"X": 1})
+    assert _fused(chain.triggers["X"], chain.program.dims) == []
+    _on_tpu(monkeypatch, False)
+    assert _fused(trig, dims) == []
+
+
+@pytest.mark.parametrize("donate", [False, True])
+def test_fused_pass_engine_matches_reeval(monkeypatch, donate):
+    """Rank-1 updates through the pass (the kernel interpreted on the
+    CPU; with donation, writing over the view's buffer) match
+    re-evaluation and the engine staged as before, and each firing
+    counts one pass per view the selection picked."""
+    n = 256
+    plain = IncrementalEngine(matrix_powers(k=16, n=n))
+    _on_tpu(monkeypatch)
+    eng = IncrementalEngine(matrix_powers(k=16, n=n), donate=donate)
+    ree = ReevalEngine(matrix_powers(k=16, n=n))
+    for e in (plain, eng, ree):
+        e.initialize(_powers_inputs(n))
+    assert plain._trigger_fns["A"].fused_views == ()
+    assert list(eng._trigger_fns["A"].fused_views) == _fused_rank1()
+    ups = _updates(n, n, 6, seed=21)
+    for u, v in ups:
+        plain.apply_update("A", u, v, block=True)
+        eng.apply_update("A", u, v, block=True)
+        ree.apply_update("A", jnp.asarray(u), jnp.asarray(v))
+    assert max_abs_diff(eng.views, ree.views) < 1e-3
+    for name in POWERS_RANK1:
+        assert_close(eng.views[name], plain.views[name], rtol=1e-5,
+                     atol=1e-6)
+    assert eng.stats.triggers_fired == len(ups)
+    assert eng.stats.fused_view_passes == len(_fused_rank1()) * len(ups)
+    assert plain.stats.fused_view_passes == 0
+
+
+@pytest.mark.parametrize("fault", ["chaos", "nonfinite"])
+def test_fused_pass_guarded_rollback(monkeypatch, fault):
+    """A guarded engine whose firings take the pass still rolls an
+    aborted firing back bit for bit: a fault raised in the trigger
+    restores the same buffers and every counter (the trigger cache's
+    build count aside), and a non-finite output is selected out inside
+    the fused program."""
+    import dataclasses
+    from repro.guard import ChaosConfig, GuardConfig
+    _on_tpu(monkeypatch)
+    n = 256
+    eng = IncrementalEngine(matrix_powers(k=16, n=n), guard=GuardConfig(),
+                            chaos=ChaosConfig(seed=0))
+    eng.initialize(_powers_inputs(n))
+    for u, v in _updates(n, n, 2, seed=5):
+        eng.apply_update("A", u, v, block=True)
+    eng.guard.sync()
+    assert eng.stats.fused_view_passes == 2 * len(_fused_rank1())
+    before_views = dict(eng.views)
+    before = {k: np.asarray(a) for k, a in eng.views.items()}
+    before_stats = dataclasses.replace(eng.stats)
+    u = np.zeros((n, 1), np.float32)
+    u[3] = 1.0
+    if fault == "chaos":
+        eng.chaos.config = dataclasses.replace(eng.chaos.config,
+                                               trigger_raise_p=1.0)
+        v = np.full((n, 1), 0.01, np.float32)
+        out = eng.apply_update("A", u, v, block=True)
+        for k, arr in before_views.items():
+            assert out[k] is arr, k
+        assert eng.stats == dataclasses.replace(
+            before_stats, trigger_builds=eng.stats.trigger_builds)
+    else:
+        v = np.full((n, 1), 3e38, np.float32)
+        eng.apply_update("A", u, v, block=True)
+        eng.guard.sync()
+    for k, arr in before.items():
+        np.testing.assert_array_equal(np.asarray(eng.views[k]), arr)
+    assert eng.guard.stats.rollbacks == 1
