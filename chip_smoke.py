@@ -239,11 +239,9 @@ class Smoke:
         X = rng.standard_normal((n, m), np.float32)
         W1 = rng.standard_normal((m, k), np.float32) / np.float32(m ** 0.5)
         W2 = rng.standard_normal((k, k), np.float32) / np.float32(k ** 0.5)
-        # rowlocal_apply="jit": the staged row-slab trigger, which "auto"
-        # picks on the TPU, also under --cpu-rehearsal
         eng = IncrementalEngine(prog, {"X": 1}, apply_backend=backend,
                                 max_batch_rank=8, flush_size=16,
-                                flush_age=3600.0, rowlocal_apply="jit")
+                                flush_age=3600.0)
         t0 = time.perf_counter()
         eng.initialize({"X": X, "W1": W1, "W2": W2})
         self.jax.block_until_ready(eng.views)

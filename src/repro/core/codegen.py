@@ -522,112 +522,6 @@ def compact_chain_names(trigger: Trigger):
     return left
 
 
-def _np_evaluate(e: Expr, env: Env, binding: Dict[str, int],
-                 cache: Dict[int, "np.ndarray"]):
-    """Numpy twin of :func:`evaluate` for the in-place compact path.
-
-    A compact firing's factor chain is a handful of skinny matmuls on
-    `(r, k)`-sized arrays — eager jax dispatch overhead dwarfs the
-    arithmetic there, so the host path evaluates with numpy directly
-    (same op semantics, float32 throughout)."""
-    import numpy as np
-
-    def go(x: Expr):
-        hit = cache.get(id(x))
-        if hit is not None:
-            return hit
-        if isinstance(x, ex.Var):
-            out = np.asarray(env[x.name])
-        elif isinstance(x, ex.Zero):
-            out = np.zeros((_dim(x.shape[0], binding),
-                            _dim(x.shape[1], binding)), np.float32)
-        elif isinstance(x, ex.Identity):
-            out = np.eye(_dim(x.shape[0], binding), dtype=np.float32)
-        elif isinstance(x, ex.Const):
-            out = np.full((1, 1), x.value, np.float32)
-        elif isinstance(x, ex.MatMul):
-            out = go(x.lhs) @ go(x.rhs)
-        elif isinstance(x, ex.Add):
-            out = functools.reduce(np.add, [go(t) for t in x.terms])
-        elif isinstance(x, ex.Scale):
-            f = go(x.factor)
-            if f.ndim == 2:  # (1,1) scalar view
-                f = f[0, 0]
-            out = f * go(x.operand)
-        elif isinstance(x, ex.Transpose):
-            out = go(x.operand).T
-        elif isinstance(x, ex.Inverse):
-            a = go(x.operand)
-            out = 1.0 / a if a.shape == (1, 1) else np.linalg.inv(a)
-        elif isinstance(x, HStack):
-            out = np.concatenate([go(b) for b in x.blocks], axis=1)
-        elif isinstance(x, ColSlice):
-            out = go(x.operand)[:, x.col:x.col + 1]
-        else:
-            raise TypeError(f"cannot evaluate {type(x).__name__}")
-        cache[id(x)] = out
-        return out
-
-    return go(e)
-
-
-def build_rowlocal_inplace_fn(trigger: Trigger, program: Program,
-                              binding: Optional[Dict[str, int]] = None):
-    """In-place CPU apply for a fully row-local trigger, or ``None``.
-
-    XLA on CPU ignores buffer donation, so every jitted firing rewrites
-    each written view in full — a copy floor that swamps the row-slab
-    win no matter how contained the update is (at serving shapes the
-    floor is tens of milliseconds of pure memcpy).  When the trigger's
-    whole factor chain is compact (:func:`compact_chain_names`), none
-    of that machinery is needed: this builder returns
-    ``run(views, rows, block, v) -> views`` which evaluates the chain
-    eagerly on the compact ``(r, k)`` factors and mutates each view's
-    rows **in place** — ``view[rows] += L @ Rᵀ`` on mutable ``np``
-    storage — touching exactly ``r·m`` elements per view and nothing
-    else.  No padding, no rank buckets, no compile cache: shapes are
-    data, not program structure.
-
-    Views still held as jax arrays are converted to ``np`` storage once
-    (a final copy); later jit firings re-ingest them transparently, so
-    mixed carrier/dense streams stay exact and pay one conversion per
-    regime switch instead of a copy floor per firing.  Engines engage
-    this path only when unguarded (transactional rollback needs the
-    staged copy-on-write firing) — see
-    ``IncrementalEngine(rowlocal_apply=...)``.
-    """
-    names = compact_chain_names(trigger)
-    if names is None:
-        return None
-    binding = dict(program.dims if binding is None else binding)
-    written, read_only = trigger_touched_views(trigger)
-    import numpy as np
-
-    def run(views: Env, rows, block, v) -> Env:
-        rows = np.asarray(rows, dtype=np.int32)
-        env: Env = {}
-        for name in written:
-            arr = views[name]
-            if not isinstance(arr, np.ndarray):
-                arr = np.array(arr, dtype=np.float32)
-                views[name] = arr
-            env[name] = arr
-        for name in read_only:
-            env[name] = views[name]
-        env[trigger.u_var.name] = np.asarray(block, dtype=np.float32)
-        env[trigger.v_var.name] = np.asarray(v, dtype=np.float32)
-        cache: Dict[int, "np.ndarray"] = {}
-        for a in trigger.assigns:
-            env[a.name] = _np_evaluate(a.expr, env, binding, cache)
-        for up in trigger.updates:
-            L = env[up.u]
-            R = env[up.v]
-            views[up.view][rows] += L @ R.T
-        return views
-
-    return run
-
-
 def build_rowlocal_trigger_fn(trigger: Trigger, program: Program,
                               binding: Optional[Dict[str, int]] = None,
                               row_bucket: int = 8,

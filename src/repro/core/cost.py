@@ -232,17 +232,6 @@ def batch_crossover_rank(view_shape: Tuple[int, int],
 # ---------------------------------------------------------------------------
 
 
-def rowlocal_apply_cost(view_shape: Tuple[int, int], rank: int,
-                        rows: int) -> Cost:
-    """Cost of the row-slab GER: ``M[rows] += B Vᵀ`` touching ``rows``
-    of the n rows.  FLOPs and M-traffic both scale with the affected
-    row count — the §3 "local change" priced as data instead of
-    structure.  The right factor still crosses memory whole."""
-    n, m = view_shape
-    r = min(int(rows), n)
-    return Cost(2.0 * rank * r * m, ELT * (2 * r * m + rank * (r + m)))
-
-
 def rowlocal_crossover_fraction(view_shape: Tuple[int, int], rank: int,
                                 efficiency: float = 0.5) -> float:
     """Affected fraction below which the row-slab sweep beats the dense

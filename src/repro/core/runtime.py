@@ -38,9 +38,8 @@ import numpy as np
 
 from .. import obs
 from .codegen import (Applier, build_evaluator,
-                      build_planned_trigger_fn, build_rowlocal_inplace_fn,
-                      build_rowlocal_trigger_fn, build_trigger_fn, evaluate,
-                      trigger_flops)
+                      build_planned_trigger_fn, build_rowlocal_trigger_fn,
+                      build_trigger_fn, evaluate, trigger_flops)
 from .compiler import (CompiledProgram, Trigger, batch_bucket,
                        compile_batched_trigger, compile_delta_trigger,
                        compile_program)
@@ -120,7 +119,6 @@ class IncrementalEngine:
                  max_batch_rank: Optional[int] = None,
                  recompress_tol: float = 1e-6,
                  rowlocal_fraction: float = 0.25,
-                 rowlocal_apply: str = "auto",
                  flush_size: int = 16,
                  flush_age: float = 0.1,
                  flush_policy: str = "fixed",
@@ -188,21 +186,7 @@ class IncrementalEngine:
         variant (sweeps only the touched rows of every view the
         compiler proved row-local); above it the carrier widens to the
         dense factored path, which stays the bit-exact oracle.
-
-        ``rowlocal_apply`` picks how a contained row-slab firing
-        executes: ``"jit"`` always stages the row-slab XLA program;
-        ``"inplace"`` mutates the touched rows of each view directly on
-        mutable host storage
-        (:func:`~repro.core.codegen.build_rowlocal_inplace_fn`) when
-        the trigger's whole factor chain is compact — on CPU, where XLA
-        ignores buffer donation, this removes the per-firing full-view
-        rewrite entirely; ``"auto"`` (default) is ``"inplace"`` on the
-        CPU backend and ``"jit"`` elsewhere.  Guarded/chaos engines and
-        triggers with any widened view always use the staged path (the
-        transaction needs copy-on-write rollback).
         """
-        if rowlocal_apply not in ("auto", "jit", "inplace"):
-            raise ValueError(f"unknown rowlocal_apply {rowlocal_apply!r}")
         if flush_policy not in ("fixed", "cost"):
             raise ValueError(f"unknown flush_policy {flush_policy!r}")
         if isinstance(order, dict):
@@ -279,10 +263,6 @@ class IncrementalEngine:
         # row-slab trigger variants, keyed (input, rank bucket, row bucket)
         self._rowlocal_fns: Dict[Tuple, Callable] = {}
         self.rowlocal_fraction = float(rowlocal_fraction)
-        self.rowlocal_apply = rowlocal_apply
-        # in-place compact appliers, keyed by input (None = chain not
-        # compact); built lazily on first contained firing
-        self._rowlocal_inplace_fns: Dict[str, Optional[Callable]] = {}
         # batching policy: cap the stacked rank (QR/SVD re-compression past
         # it) and the queue flush thresholds (size in stacked rank,
         # staleness in seconds).
@@ -1133,19 +1113,6 @@ class IncrementalEngine:
         rows0, B0, V0 = rows, B, V  # pre-padding (what an abort keeps)
         rank = B.shape[1]
         n_in = int(carrier.nm[0])
-        if (self.guard is None and self.chaos is None
-                and (self.rowlocal_apply == "inplace"
-                     or (self.rowlocal_apply == "auto"
-                         and jax.default_backend() == "cpu"))):
-            infn = self._rowlocal_inplace_fn(input_name)
-            if infn is not None:
-                # unguarded compact chain: mutate the touched rows in
-                # place — no padding, no staged program, no copy floor
-                with obs.span("engine.dispatch"):
-                    self.views = infn(self.views, rows, B, V)
-                return self._rowlocal_epilogue(input_name, carrier, rank,
-                                               int(rows.shape[0]), t0,
-                                               block, t_count, stacked_rank)
         base = self.compiled.triggers[input_name].rank
         rank_bucket = rank if rank == base else batch_bucket(rank)
         if rank_bucket != rank:
@@ -1198,24 +1165,14 @@ class IncrementalEngine:
         self.stats.fused_view_passes += len(getattr(fn, "fused_views", ()))
         self.stats.collective_bytes += getattr(fn, "collective_bytes", 0)
 
-    def _rowlocal_inplace_fn(self, input_name: str) -> Optional[Callable]:
-        """The in-place compact applier for ``input_name``'s trigger
-        (``None`` when its factor chain is not compact), built once."""
-        if input_name not in self._rowlocal_inplace_fns:
-            self._rowlocal_inplace_fns[input_name] = \
-                build_rowlocal_inplace_fn(
-                    self.compiled.triggers[input_name], self.program,
-                    self.binding)
-        return self._rowlocal_inplace_fns[input_name]
-
     def _rowlocal_epilogue(self, input_name: str, carrier: RowLocalCarrier,
                            rank: int, r: int, t0: float, block: bool,
                            t_count: int, stacked_rank: Optional[int]
                            ) -> Dict[str, Array]:
-        """Shared accounting tail of a row-slab firing (staged or
-        in-place): plan staleness, timed-sweep stats, firing counters,
-        and the planner's observed affected fraction.  ``rank`` is the
-        rank fired (padded to its bucket on the staged path);
+        """Shared accounting tail of a row-slab firing: plan
+        staleness, timed-sweep stats, firing counters, and the planner's
+        observed affected fraction.  ``rank`` is the rank fired (padded
+        to its bucket);
         ``stacked_rank`` the batch's rank before re-compression, where
         it had one."""
         if self.plan is not None:

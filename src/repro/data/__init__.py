@@ -1,5 +1,6 @@
 """Data substrate: deterministic shard-aware synthetic pipelines and the
-update-stream generators used by the IVM benchmarks."""
+update-stream generators that drive the engine (tests, apps, the serve
+and train launchers)."""
 
 from .pipeline import TokenPipeline, make_batch_specs, synth_batch
 from .updates import (LabeledStream, LabeledUpdate, RowLocalStream,

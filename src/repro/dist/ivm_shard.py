@@ -15,8 +15,9 @@ mesh makes each firing embarrassingly parallel —
 Re-evaluation on the same layout moves whole matrices: one n×n matmul
 between two row-sharded operands all-gathers O(n²) bytes.  That gap is
 the paper's Fig. 3f finding (INCR is far less sensitive to cluster size
-than REEVAL), reproduced structurally by ``benchmarks/bench_scaling.py``
-from the compiled collective schedules of the two functions below.
+than REEVAL), visible in the compiled collective schedules of the two
+functions below (:func:`collective_bytes` counts a compiled
+program's collective operand bytes).
 
 Placement is declared with ``with_sharding_constraint`` inside the staged
 computation (views by rows, the sweeps' right factors replicated: see

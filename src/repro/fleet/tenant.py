@@ -274,8 +274,7 @@ class TenantRegistry:
 
     The cache is THE cross-tenant fast path: same-program tenants key
     to identical (fingerprint, backend, tail) entries, so the second
-    tenant's triggers come back pre-jitted (benchmarks/bench_fleet.py
-    measures the aggregate win).
+    tenant's triggers come back pre-jitted and it builds none.
     """
 
     def __init__(self, trigger_cache: Optional[TriggerCache] = None,

@@ -289,6 +289,30 @@ def test_ridge_matches_batch_retrain(seed, case, lam):
 
 
 @pytest.mark.parametrize("seed", CHAOS_SEEDS)
+def test_past_crossover_refresh_prices_refactor(seed):
+    """Between refreshes, a backlog past the solver crossover
+    (``2·k*`` events) takes the refactor arm every time and one below
+    it takes rank-1 updates; every refresh matches batch retrain."""
+    spec = RingSpec(features=12, targets=1, capacity=64)
+    ring = Ring(spec)
+    s = labeled_stream(spec.features, capacity=spec.capacity, churn=0.0,
+                       seed=seed + 41)
+    drive(ring, s, int(0.9 * spec.capacity))
+    solver = RidgeSolver(ring, lam=0.5)
+    solver.coefficients()
+    s.churn = 0.45
+    k_star = solver_crossover_rank(spec.features)
+    for backlog, want in ((2 * k_star, "refactor"), (2 * k_star, "refactor"),
+                          (k_star - 1, "update")):
+        drive(ring, s, backlog)
+        B = solver.coefficients()
+        assert solver.stats.strategy_log[-1] == want, \
+            (backlog, solver.stats.strategy_log)
+        Xl, Yl = ring.live_data()
+        assert np.abs(B - batch_ridge(Xl, Yl, 0.5)).max() < 1e-5
+
+
+@pytest.mark.parametrize("seed", CHAOS_SEEDS)
 def test_ridge_after_delete_heavy_churn(seed):
     spec = RingSpec(features=6, targets=1, capacity=64)
     ring = Ring(spec)

@@ -611,6 +611,47 @@ def test_serve_engine_attach_fleet():
 # satellite: thread-safe TriggerCache
 # ---------------------------------------------------------------------------
 
+def test_same_program_tenants_reuse_the_first_tenants_triggers():
+    """Tenants registered after the first, on the same program, get
+    every trigger from the fleet's shared cache: they build none, the
+    cache misses stay at the first tenant's, and each still commits
+    views bit-identical to its own replay."""
+    fleet = FleetScheduler(FleetConfig(lease_ttl=60.0))
+    cache = fleet.registry.trigger_cache
+    rng = np.random.default_rng(5)
+    tenant_inputs, by_lsn = {}, {}
+
+    def bring_up(tid, seed):
+        prog, inputs = _logit_tenant(seed=seed)
+        tenant_inputs[tid] = inputs
+        fleet.add_tenant(TenantSpec(tid, prog, {"W": 1}, max_claim_rank=4),
+                         inputs)
+        by_lsn[tid] = {}
+        for lsn in range(1, 5):
+            u, v = _rank1(rng, 5, 4)
+            assert fleet.submit(tid, "W", u, v) == ADMITTED
+            by_lsn[tid][lsn] = (u, v)
+        fleet.run_until_idle(workers=1)
+
+    bring_up("t0", 0)
+    first = fleet.registry.get("t0")
+    assert first.engine.stats.trigger_builds > 0
+    misses = cache.stats()["misses"]
+    assert misses == first.engine.stats.trigger_builds
+    for i in (1, 2):
+        bring_up(f"t{i}", i)
+    assert cache.stats()["misses"] == misses
+    assert cache.stats()["hits"] > 0
+    for tid in ("t1", "t2"):
+        tenant = fleet.registry.get(tid)
+        assert tenant.engine.stats.trigger_builds == 0
+        assert tenant.stats.committed_updates == 4
+        assert all(tenant.engine._trigger_fns[name] is fn for name, fn
+                   in first.engine._trigger_fns.items())
+        ref = _replay_reference(tenant, tenant_inputs[tid], by_lsn[tid])
+        assert max_abs_diff(tenant.committed_views, ref.views) == 0.0
+
+
 def test_trigger_cache_concurrent_access():
     cache = TriggerCache(capacity=8)
     built = []

@@ -395,6 +395,34 @@ def test_engine_adopts_plan_depth_and_stays_exact():
     assert eng.stats.folds > 0
 
 
+def test_depth2_folds_once_per_window_without_reads():
+    """With no reads, a depth-2 engine folds exactly once every
+    ``fold_window`` firings; a partial window waits for the next read,
+    which folds it and serves exact views."""
+    prog = build_powers_program(4, 12)
+    rng = np.random.default_rng(17)
+    inputs = _gen_inputs(prog, rng, {"A": 0.25})
+    w = 4
+    eng = IncrementalEngine(prog, order=2, fold_window=w,
+                            max_fold_rank=None)
+    ref = ReevalEngine(prog)
+    eng.initialize(inputs)
+    ref.initialize(inputs)
+    windows = 3
+    for i in range(windows * w + 2):
+        ups = _ragged_stream(rng, (12, 12), T=2)
+        eng.apply_updates("A", ups)
+        for u, v in ups:
+            ref.apply_update("A", u, v)
+        assert eng.stats.folds == (i + 1) // w
+    assert eng.stats.folds == windows
+    assert eng._cascade_pending()
+    eng.flush()
+    assert eng.stats.folds == windows + 1
+    assert not eng._cascade_pending()
+    _assert_views_match(eng, ref, 1e-6, label="d2 windows: ")
+
+
 def test_engine_rejects_lazy_plus_deferred_plan():
     prog = build_powers_program(4, 12)
     compiled = compile_program(prog)

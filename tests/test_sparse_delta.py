@@ -267,6 +267,38 @@ def test_noop_gate_on_rowlocal_carrier_and_nan_falls_through():
     assert max_abs_diff(eng.views, before) == 0.0
 
 
+def test_noop_stream_skips_every_declared_noop():
+    """A stream that is 95% declared no-ops, one update per call: every
+    NoOpCarrier is skipped and counted, only the live carriers fire, and
+    the views equal a dense engine fed the no-ops as zero factor pairs
+    (which it must fire)."""
+    prog = _chain_prog()
+    carrier_eng = IncrementalEngine(prog, {"X": 1})
+    dense_eng = IncrementalEngine(prog, {"X": 1})
+    for e in (carrier_eng, dense_eng):
+        e.initialize(_chain_inputs(2))
+    live = row_local_stream(64, 4, m=32, rank=1, seed=2)
+    zero_u = np.zeros((64, 1), np.float32)
+    zero_v = np.zeros((32, 1), np.float32)
+    total, live_every = 40, 20
+    for i in range(total):
+        if i % live_every == 0:
+            c = live.next_carrier()
+            carrier_eng.apply_update("X", c)
+            dense_eng.apply_update("X", *c.factors())
+        else:
+            carrier_eng.apply_update("X", NoOpCarrier(64, 32))
+            dense_eng.apply_update("X", zero_u, zero_v)
+    declared = total - total // live_every
+    assert carrier_eng.stats.noop_skips == declared
+    assert carrier_eng.stats.updates_applied == total
+    assert carrier_eng.stats.triggers_fired == total // live_every
+    assert dense_eng.stats.triggers_fired == total
+    for name in ("Y1", "Y2"):
+        assert_close(carrier_eng.views[name], dense_eng.views[name],
+                     msg=name)
+
+
 # ---------------------------------------------------------------------------
 # fleet: mixed-carrier tenants, replay bit-identity under chaos
 # ---------------------------------------------------------------------------
@@ -449,7 +481,7 @@ def test_stream_batch_is_dense_equivalent():
 
 
 # ---------------------------------------------------------------------------
-# P9: compact-chain analysis and the in-place CPU apply
+# P9: compact-chain analysis and the compact row-slab firing
 # ---------------------------------------------------------------------------
 
 def test_compact_chain_names_chain_vs_gram():
@@ -465,35 +497,35 @@ def test_compact_chain_names_chain_vs_gram():
 
 
 def test_inplace_apply_matches_staged_and_mutates_in_place():
+    """Contained carriers on a compact chain fire the staged row-slab
+    program and agree with the same deltas widened to dense factors,
+    before and after a dense firing on the same engine."""
     n, m, k = 96, 24, 12
     inputs = _chain_inputs(5, n, m, k)
-    auto = IncrementalEngine(_chain_prog(n, m, k), {"X": 2})
-    staged = IncrementalEngine(_chain_prog(n, m, k), {"X": 2},
-                               rowlocal_apply="jit")
-    auto.initialize(inputs)
-    staged.initialize(inputs)
+    carrier_eng = IncrementalEngine(_chain_prog(n, m, k), {"X": 2})
+    dense_eng = IncrementalEngine(_chain_prog(n, m, k), {"X": 2})
+    carrier_eng.initialize(inputs)
+    dense_eng.initialize(inputs)
     s = row_local_stream(n, 3, m=m, rank=2, scale=0.1, seed=7)
-    probe = row_local_stream(n, 3, m=m, rank=2, scale=0.1, seed=7)
     for _ in range(6):
-        auto.apply_update("X", s.next_carrier())
-        staged.apply_update("X", probe.next_carrier())
-    # on the CPU backend "auto" engages the in-place path: the written
-    # views live on mutable np storage and later firings reuse it
-    assert isinstance(auto.views["Y2"], np.ndarray)
-    assert not isinstance(staged.views["Y2"], np.ndarray)
-    assert auto.stats.rowlocal_firings == 6
-    assert staged.stats.rowlocal_firings == 6
+        c = s.next_carrier()
+        carrier_eng.apply_update("X", c)
+        dense_eng.apply_update("X", *c.factors())
+    assert carrier_eng.stats.rowlocal_firings == 6
+    assert carrier_eng.stats.widened_carriers == 0
+    assert dense_eng.stats.rowlocal_firings == 0
     for name in ("X", "Y1", "Y2"):
-        assert_close(np.asarray(auto.views[name]),
-                     np.asarray(staged.views[name]), atol=1e-4)
-    # a dense firing after in-place firings re-ingests np views exactly
+        assert_close(np.asarray(carrier_eng.views[name]),
+                     np.asarray(dense_eng.views[name]), atol=1e-4)
+    # a dense firing after the row-slab firings agrees too
     rng = np.random.default_rng(8)
     u = (0.1 * rng.standard_normal((n, 2))).astype(np.float32)
     v = (0.1 * rng.standard_normal((m, 2))).astype(np.float32)
-    auto.apply_update("X", u, v)
-    staged.apply_update("X", u, v)
-    assert_close(np.asarray(auto.views["Y2"]),
-                 np.asarray(staged.views["Y2"]), atol=1e-4)
+    carrier_eng.apply_update("X", u, v)
+    dense_eng.apply_update("X", u, v)
+    assert carrier_eng.stats.rowlocal_firings == 6
+    assert_close(np.asarray(carrier_eng.views["Y2"]),
+                 np.asarray(dense_eng.views["Y2"]), atol=1e-4)
 
 
 def test_guarded_engine_keeps_staged_rowlocal_path():

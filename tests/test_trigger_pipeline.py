@@ -503,7 +503,7 @@ def test_pallas_fallbacks_counted(path):
                      ).astype(np.float32) for name in ("X", "W1", "W2")}
     guarded = path.startswith("guarded")
     engines = [IncrementalEngine(_prime_chain(n), {"X": 1},
-                                 apply_backend=backend, rowlocal_apply="jit",
+                                 apply_backend=backend,
                                  guard=True if guarded else None)
                for backend in ("pallas", "xla")]
     for eng in engines:
