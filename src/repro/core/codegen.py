@@ -239,8 +239,8 @@ def _product_of(x: Expr) -> Optional[Tuple[str, str]]:
     return None
 
 
-def fused_view_passes(trigger: Trigger, binding: Dict[str, int]
-                      ) -> Tuple[FusedPass, ...]:
+def fused_view_passes(trigger: Trigger, binding: Dict[str, int],
+                      shards: int = 1) -> Tuple[FusedPass, ...]:
     """The written views of ``trigger`` to apply through
     :func:`repro.kernels.ops.apply_project`, in trigger order.
 
@@ -251,7 +251,12 @@ def fused_view_passes(trigger: Trigger, binding: Dict[str, int]
     assign that reads a product; when the update's rank and the
     products' widths lie in :data:`FUSED_PASS_RANKS`; and when a
     blocking of the view fits VMEM.  In matrix powers, ``P_j``'s
-    products are taken against its own ``dU_j``/``dV_j``."""
+    products are taken against its own ``dU_j``/``dV_j``.
+
+    ``shards`` is the number of row blocks each view is split into
+    (:mod:`repro.dist.ivm_shard` runs the pass once per block): the
+    blocking is judged on the ``(rows / shards, cols)`` block a device
+    holds, and a view whose rows do not split evenly takes no pass."""
     if not on_tpu():
         return ()
     from repro.kernels.ops import apply_project_blocks
@@ -297,8 +302,10 @@ def fused_view_passes(trigger: Trigger, binding: Dict[str, int]
         big = (px.lhs if px is not None else pty.lhs.operand).shape
         kx = _dim(px.rhs.shape[1], binding) if px is not None else 0
         ky = _dim(pty.rhs.shape[1], binding) if pty is not None else 0
-        if apply_project_blocks(_dim(big[0], binding), _dim(big[1], binding),
-                                widths[0], kx, ky) is None:
+        rows = _dim(big[0], binding)
+        if rows % shards or apply_project_blocks(
+                rows // shards, _dim(big[1], binding), widths[0], kx,
+                ky) is None:
             continue
         out.append(FusedPass(up.view, up.u, up.v, at, px, pty))
     return tuple(out)
@@ -433,14 +440,21 @@ def build_trigger_fn(trigger: Trigger, program: Program,
 
 def _run_fused_pass(fp: FusedPass, env: Env, binding: Dict[str, int],
                     cache: Dict[int, Array], applied: Env,
-                    donate: bool) -> None:
+                    donate: bool, project: Optional[Callable] = None
+                    ) -> None:
     """One apply-and-project pass: the new view goes to ``applied`` (the
-    assigns still read the old one), the products to the CSE cache."""
-    from repro.kernels import ops as rk_ops
+    assigns still read the old one), the products to the CSE cache.
+    ``project(p, u, v, x, y) -> (p + u vᵀ, p·x, pᵀ·y)`` runs the pass:
+    by default :func:`repro.kernels.ops.apply_project`, over ``p``'s
+    buffer when ``donate``; the row-sharded firing gives one that runs
+    it per row block."""
+    if project is None:
+        from repro.kernels import ops as rk_ops
+        project = functools.partial(rk_ops.apply_project, alias=donate)
     x, y = (None if e is None else evaluate(e.rhs, env, binding, cache)
             for e in (fp.px, fp.pty))
-    applied[fp.view], px, pty = rk_ops.apply_project(
-        env[fp.view], env[fp.u], env[fp.v], x, y, alias=donate)
+    applied[fp.view], px, pty = project(
+        env[fp.view], env[fp.u], env[fp.v], x, y)
     for node, val in ((fp.px, px), (fp.pty, pty)):
         if node is not None:
             cache[id(node)] = val

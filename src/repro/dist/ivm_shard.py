@@ -21,10 +21,16 @@ program's collective operand bytes).
 
 Placement is declared with ``with_sharding_constraint`` inside the staged
 computation (views by rows, the sweeps' right factors replicated: see
-:func:`right_factors`) and GSPMD inserts the collectives — the trigger body
-itself is the *same* code the single-device engine runs
-(:func:`repro.core.codegen.evaluate`), so distributed output matches
-single-device output to fp32 tolerance by construction.
+:func:`right_factors`), and GSPMD inserts the collectives of the factor
+blocks and of the plain sweeps, which :func:`repro.core.codegen.evaluate`
+stages as on one device.  The views that
+:func:`repro.core.codegen.fused_view_passes` picks for the local row
+block instead run :func:`repro.kernels.ops.apply_project` once per block
+under ``jax.shard_map``, as the one-device firing runs it on the whole
+view: one read of each block gives the new view and the products
+``P·X`` (in rows) and ``Pᵀ·Y``, whose per-block partial sums the body
+all-reduces with an explicit ``psum``.  Either way distributed output
+matches single-device output to fp32 tolerance.
 """
 
 from __future__ import annotations
@@ -36,8 +42,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.codegen import (build_evaluator, evaluate, firing_name,
-                                matmul, named, trigger_touched_views)
+from repro.core.codegen import (_run_fused_pass, build_evaluator, evaluate,
+                                firing_name, fused_view_passes, matmul,
+                                named, trigger_touched_views)
 from repro.core.compiler import Trigger
 from repro.core.program import Program
 from repro.dist.sharding import auto_axes
@@ -138,6 +145,27 @@ def collective_bytes(hlo_text: str) -> int:
     return total
 
 
+def _row_block_apply_project(mesh: Mesh, axis: str) -> Callable:
+    """``project(p, u, v, x, y) -> (p + u vᵀ, p·x, pᵀ·y)`` for a view
+    ``p`` in row blocks over ``axis``: each device runs
+    :func:`repro.kernels.ops.apply_project` on its block, with the rows
+    of ``u`` and ``y`` that match it and the whole of ``v`` and ``x``,
+    and all-reduces its partial ``pᵀ·y``.  The new view and ``p·x`` come
+    back in row blocks, ``pᵀ·y`` replicated; ``x`` or ``y`` may be
+    ``None``, and so is its product then."""
+    from repro.kernels import ops as rk_ops
+    mesh = auto_axes(mesh)
+    rows, whole = P(axis, None), P()
+
+    def body(p, u, v, x, y):
+        new, px, pty = rk_ops.apply_project(p, u, v, x, y)
+        return new, px, None if pty is None else jax.lax.psum(pty, axis)
+
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(rows, rows, whole, whole, rows),
+                         out_specs=(rows, rows, whole), check_vma=False)
+
+
 def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
                               *, jit: bool = True,
                               axis: Optional[str] = None
@@ -150,6 +178,11 @@ def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
     values (untouched views pass through).  ``axis`` defaults to the
     mesh's first axis name.
 
+    The views :func:`repro.core.codegen.fused_view_passes` picks for a
+    device's row block are applied and projected in one pass per block
+    (:func:`_row_block_apply_project`), at the same point of the trigger
+    as the one-device firing runs them; ``run.fused_views`` names them.
+
     With ``jit=False`` the returned function is a pure trace-able body
     (no internal jit) so callers can ``jax.jit(fn).lower(...)`` it to
     inspect the collective schedule.
@@ -160,6 +193,8 @@ def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
     constrain = _constrainer(mesh, axis)
     replicate = _replicator(mesh)
     right = right_factors(trigger)
+    passes = fused_view_passes(trigger, binding, mesh.shape[axis])
+    project = _row_block_apply_project(mesh, axis) if passes else None
 
     def core(written_vals: Tuple[Array, ...], read_vals: Tuple[Array, ...],
              u: Array, v: Array) -> Tuple[Array, ...]:
@@ -171,11 +206,18 @@ def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
         env[trigger.u_var.name] = replicate(u)
         env[trigger.v_var.name] = replicate(v)
         cache: Dict[int, Array] = {}
-        for a in trigger.assigns:
+        applied: Env = {}
+        for i, a in enumerate(trigger.assigns):
+            for fp in passes:
+                if fp.at == i:
+                    _run_fused_pass(fp, env, binding, cache, applied,
+                                    False, project=project)
             val = evaluate(a.expr, env, binding, cache)
             env[a.name] = replicate(val) if a.name in right else val
         for up in trigger.updates:
-            if up.kind == "lowrank":
+            if up.view in applied:
+                new = applied[up.view]
+            elif up.kind == "lowrank":
                 new = env[up.view] + matmul(env[up.u], env[up.v].T)
             else:
                 new = env[up.view] + env[up.d]
@@ -203,6 +245,7 @@ def build_distributed_trigger(trigger: Trigger, program: Program, mesh: Mesh,
 
     run.executable = None
     run.collective_bytes = 0
+    run.fused_views = tuple(fp.view for fp in passes)
     return run
 
 

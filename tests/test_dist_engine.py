@@ -364,6 +364,166 @@ def test_mesh_fold_and_refresh_keep_row_blocks(mesh4_report):
     assert reeval["refresh_rel_err"] < 1e-5, reeval
 
 
+# -- one pass per view on the mesh: apply-and-project per row block ----------
+
+# One child process with four virtual devices and ``codegen.on_tpu``
+# patched to True, so the mesh firing takes the apply-and-project pass
+# per row block (the kernel interpreted); the tests below each read their
+# part of what it saw.
+_FUSED4_SCRIPT = textwrap.dedent("""
+    import json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core import IncrementalEngine, ReevalEngine, codegen
+    from repro.core.compiler import compile_program
+    from repro.core.iterative import matrix_powers
+    from repro.roofline.analysis import parse_collectives
+
+    n, updates = {n}, 6
+    rng = np.random.default_rng(0)
+    A = (rng.normal(size=(n, n)) * 0.9 / np.sqrt(n)).astype(np.float32)
+    mesh = jax.make_mesh((4,), ("rows",))
+
+    def program():
+        return matrix_powers(k=16, n=n)
+
+    codegen.on_tpu = lambda: False
+    plain = IncrementalEngine(program(), {{"A": 1}}, mesh=mesh)
+    codegen.on_tpu = lambda: True
+    fused = IncrementalEngine(program(), {{"A": 1}}, mesh=mesh)
+    compiled = compile_program(program(), {{"A": 1}})
+    one_chip = [fp.view for fp in codegen.fused_view_passes(
+        compiled.triggers["A"], compiled.program.dims)]
+    ree = ReevalEngine(program())
+    for eng in (plain, fused, ree):
+        eng.initialize({{"A": jnp.asarray(A)}})
+    for _ in range(updates):
+        u = np.zeros((n, 1), np.float32)
+        u[int(rng.integers(n)), 0] = 1.0
+        d = (rng.normal(size=(n, 1)) * 0.3 / np.sqrt(n)).astype(np.float32)
+        plain.apply_update("A", u, d, block=True)
+        fused.apply_update("A", u, d, block=True)
+        ree.apply_update("A", jnp.asarray(u), jnp.asarray(d))
+    def close(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        return [bool(np.allclose(got, want, rtol=1e-5, atol=1e-6)),
+                float(np.max(np.abs(got - want)))]
+
+    def rel(got, want):
+        want = np.asarray(want, np.float64)
+        return float(np.max(np.abs(np.asarray(got, np.float64) - want))
+                     / np.max(np.abs(want)))
+
+    fn = fused._trigger_fns["A"]
+    ops = {{}}
+    for op in parse_collectives(fn.executable.as_text()).ops:
+        ops.setdefault(op.channel_id or id(op), op)
+    # the per-block pass alone, with both products and with either one
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.dist.ivm_shard import _row_block_apply_project
+    from repro.dist.sharding import auto_axes
+    from repro.kernels import ref
+    project = jax.jit(_row_block_apply_project(mesh, "rows"))
+    p = jax.device_put(jnp.asarray(A), NamedSharding(
+        auto_axes(mesh), PartitionSpec("rows", None)))
+    u, v, x, y = (jnp.asarray(rng.normal(size=(n, w)), jnp.float32)
+                  for w in (2, 2, 3, 4))
+    alone = {{}}
+    for case, xy in {{"both": (x, y), "x": (x, None), "y": (None, y)}}.items():
+        got, want = project(p, u, v, *xy), ref.apply_project(p, u, v, *xy)
+        alone[case] = [None if g is None else
+                       [rel(g, w), str(g.sharding.spec)]
+                       for g, w in zip(got, want)]
+    print(json.dumps({{
+        "alone": alone,
+        "updates": updates, "one_chip": one_chip,
+        "fused_views": list(fn.fused_views),
+        "plain_views": list(plain._trigger_fns["A"].fused_views),
+        "passes": fused.stats.fused_view_passes,
+        "plain_passes": plain.stats.fused_view_passes,
+        "fired": fused.stats.triggers_fired,
+        "views": {{k: [close(fused.views[k], plain.views[k]),
+                      rel(fused.views[k], ree.views[k]),
+                      str(fused.views[k].sharding.spec)]
+                  for k in fused.views}},
+        "largest_collective": max(o.operand_bytes for o in ops.values()),
+        "collective_bytes": [fused.stats.collective_bytes,
+                             plain.stats.collective_bytes]}}))
+""")
+
+
+@pytest.fixture(scope="module")
+def mesh4_fused_report():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run(
+        [sys.executable, "-c", _FUSED4_SCRIPT.format(n=_MESH4_N)], env=env,
+        capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, f"STDOUT:\n{res.stdout}\nSTDERR:\n{res.stderr}"
+    import json
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_fused_pass_selection_is_the_one_chip_selection(
+        mesh4_fused_report):
+    """On a TPU the mesh firing applies and projects the views the
+    one-device firing does (A, P2, P4, P8), each 64×256 block fitting a
+    tile; without a TPU it takes no pass."""
+    rep = mesh4_fused_report
+    assert rep["fused_views"] == rep["one_chip"] == ["A", "P2", "P4", "P8"]
+    assert rep["plain_views"] == []
+
+
+@pytest.mark.parametrize("name", _VIEWS)
+def test_mesh_fused_pass_views_match_mesh_and_reeval(mesh4_fused_report,
+                                                     name):
+    """After six rank-1 updates each view, in row blocks, matches the
+    mesh engine staged without the pass (``rtol=1e-5``) and a
+    re-evaluation."""
+    (close, diff), vs_reeval, spec = mesh4_fused_report["views"][name]
+    assert spec == _ROW_BLOCKS[0]
+    assert close, diff
+    assert vs_reeval < 1e-5, vs_reeval
+
+
+@pytest.mark.parametrize("case", ["both", "x", "y"])
+def test_mesh_row_block_pass_matches_reference(mesh4_fused_report, case):
+    """The pass under ``shard_map`` gives ``P + U Vᵀ`` and ``P·X`` in row
+    blocks and the all-reduced ``Pᵀ·Y`` replicated, with both products
+    or with either alone."""
+    new, px, pty = mesh4_fused_report["alone"][case]
+    assert new == [pytest.approx(0, abs=1e-6), _ROW_BLOCKS[0]], new
+    if case == "y":
+        assert px is None
+    else:
+        assert px[0] < 1e-5 and px[1] == _ROW_BLOCKS[0], px
+    if case == "x":
+        assert pty is None
+    else:
+        assert pty[0] < 1e-5 and pty[1] == "PartitionSpec()", pty
+
+
+def test_mesh_fused_pass_counts_each_firing(mesh4_fused_report):
+    """``EngineStats.fused_view_passes`` adds the four passes of every
+    committed mesh firing."""
+    rep = mesh4_fused_report
+    assert rep["fired"] == rep["updates"]
+    assert rep["passes"] == 4 * rep["updates"]
+    assert rep["plain_passes"] == 0
+
+
+def test_mesh_fused_pass_moves_no_view_shard(mesh4_fused_report):
+    """No collective of the compiled firing carries a view's row block
+    (n·n·4/4 bytes): the explicit ``psum`` all-reduces the n×k partials
+    of ``Pᵀ·Y`` and the gathers carry n×k factors; the count is the
+    firing without the pass's."""
+    rep = mesh4_fused_report
+    assert 0 < rep["largest_collective"] < _MESH4_N * _MESH4_N * 4 // 4
+    fused, plain = rep["collective_bytes"]
+    assert 0 < fused <= 1.5 * plain
+
+
 # -- cost-model-driven auto-flush ---------------------------------------------
 
 

@@ -146,3 +146,41 @@ def test_fused_trigger_firing_compiles(one_chip, monkeypatch):
     text = _compiled_text(fire, one_chip, (WIDE, 1), (WIDE, 1),
                           *[(WIDE, WIDE)] * len(names))
     assert text.count("tpu_custom_call") == len(run.fused_views)
+
+
+def test_sharded_fused_firing_compiles(topo, monkeypatch):
+    """The row-sharded rank-1 firing at n=32768 on the four chips of a
+    v5e 2x2 host, as a TPU stages it: one apply-and-project kernel per
+    8192×32768 row block of each view the selection picks, and no
+    collective that carries a block (1 GiB); only n×k factors and
+    partial sums cross."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.core import codegen
+    from repro.core.compiler import compile_program
+    from repro.core.iterative import matrix_powers
+    from repro.dist.ivm_shard import build_distributed_trigger
+    from repro.roofline.analysis import parse_collectives
+    monkeypatch.setattr(codegen, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "interpret_mode", lambda interpret=None: False)
+    n = 2 * WIDE
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("rows",))
+    compiled = compile_program(matrix_powers(k=16, n=n), {"A": 1})
+    run = build_distributed_trigger(compiled.triggers["A"], compiled.program,
+                                    mesh, jit=False)
+    assert run.fused_views == ("A", "P2", "P4", "P8")
+    names = ["A", "P2", "P4", "P8", "P16"]
+
+    def fire(u, v, *views):
+        out = run(dict(zip(names, views)), u, v)
+        return tuple(out[k] for k in names)
+
+    whole = NamedSharding(mesh, PartitionSpec())
+    rows = NamedSharding(mesh, PartitionSpec("rows", None))
+    args = ([jax.ShapeDtypeStruct((n, 1), jnp.float32, sharding=whole)] * 2
+            + [jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=rows)]
+            * len(names))
+    text = jax.jit(fire).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == len(run.fused_views)
+    largest = max(op.operand_bytes for op in parse_collectives(text).ops)
+    assert largest < n * n * 4 // 4

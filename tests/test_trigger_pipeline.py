@@ -577,6 +577,62 @@ def test_fused_pass_selection(monkeypatch):
     assert _fused(trig, dims) == []
 
 
+def _powers_trigger(n):
+    from repro.core.compiler import compile_program
+    compiled = compile_program(matrix_powers(k=16, n=n), {"A": 1})
+    return compiled.triggers["A"], compiled.program.dims
+
+
+def test_fused_pass_selection_one_shard_is_the_one_chip_selection(
+        monkeypatch):
+    """A shard count of 1 selects what the one-device firing selects at
+    ``powers16k``'s n=16384: A, P2, P4 and P8."""
+    from repro.core.codegen import fused_view_passes
+    trig, dims = _powers_trigger(16384)
+    _on_tpu(monkeypatch)
+    assert fused_view_passes(trig, dims, 1) == fused_view_passes(trig, dims)
+    assert _fused(trig, dims) == _fused_rank1() == ["A", "P2", "P4", "P8"]
+
+
+def test_fused_pass_selection_judges_the_row_block(monkeypatch):
+    """With the views in four row blocks (``powers32k``'s n=32768), the
+    tile fit is judged on the 8192×32768 block a device holds, and the
+    same four views qualify."""
+    from repro.core.codegen import fused_view_passes
+    judged = []
+    fit = ops.apply_project_blocks
+
+    def spy(n, m, k, kx, ky):
+        judged.append((n, m))
+        return fit(n, m, k, kx, ky)
+
+    trig, dims = _powers_trigger(32768)
+    _on_tpu(monkeypatch)
+    monkeypatch.setattr(ops, "apply_project_blocks", spy)
+    passes = fused_view_passes(trig, dims, 4)
+    assert [fp.view for fp in passes] == _fused_rank1()
+    assert set(judged) == {(8192, 32768)}
+
+
+def test_fused_pass_selection_block_without_tile_takes_no_pass(monkeypatch):
+    """A view whose whole fits a tile but whose row block does not takes
+    no pass on the mesh: at n = 16·3·1031 the whole view has 8-row tiles,
+    while a quarter's 4·3·1031 rows have no multiple of 8 to divide them,
+    so its one tile is the whole block, far past VMEM.  Rows that do not
+    split evenly over the shards take no pass either."""
+    from repro.core.codegen import fused_view_passes
+    n = 16 * 3 * 1031
+    assert ops.apply_project_blocks(n, n, 1, 1, 1) is not None
+    assert ops.apply_project_blocks(n // 4, n, 1, 1, 1) is None
+    _on_tpu(monkeypatch)
+    trig, dims = _powers_trigger(n)
+    assert [fp.view for fp in fused_view_passes(trig, dims, 1)] == \
+        _fused_rank1()
+    assert fused_view_passes(trig, dims, 4) == ()
+    trig, dims = _powers_trigger(256)
+    assert fused_view_passes(trig, dims, 3) == ()
+
+
 @pytest.mark.parametrize("donate", [False, True])
 def test_fused_pass_engine_matches_reeval(monkeypatch, donate):
     """Rank-1 updates through the pass (the kernel interpreted on the
